@@ -27,6 +27,7 @@ import sys
 import traceback
 
 from benchmarks import common
+from repro.compile_cache import enable_compile_cache
 
 
 def main(argv: list[str] | None = None) -> None:
@@ -36,6 +37,7 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="record dump path (default BENCH_<mode>.json)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     common.set_smoke(args.smoke)
     mode = "smoke" if args.smoke else "full"
     out_path = args.json or f"BENCH_{mode}.json"

@@ -14,11 +14,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.columnar import Dictionary
+from repro.compile_cache import enable_compile_cache
 from repro.core import AugmentedDictionary
 from repro.core.feedback import learn_bucketization, store_embedding
 from repro.models.widedeep import (WideDeepConfig, init_widedeep,
                                    make_widedeep_train_step)
 
+enable_compile_cache()
 rng = np.random.default_rng(7)
 N, K, LATENT = 30_000, 200, 4
 

@@ -117,6 +117,7 @@ import time
 import numpy as np
 
 from repro.columnar import Table
+from repro.compile_cache import enable_compile_cache
 from repro.core import FeatureSet, FeaturePlan
 from repro.serve import FeatureFrontend, FeatureService, Overloaded
 
@@ -445,4 +446,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

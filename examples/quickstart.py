@@ -11,11 +11,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.columnar import Table
+from repro.compile_cache import enable_compile_cache
 from repro.core import FeatureSet, FeaturePipeline
 from repro.core.feedback import store_embedding, rank_features
 from repro.models.widedeep import (WideDeepConfig, init_widedeep,
                                    make_widedeep_train_step)
 
+enable_compile_cache()
 rng = np.random.default_rng(0)
 N = 20_000
 

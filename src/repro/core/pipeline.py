@@ -80,6 +80,7 @@ from repro.columnar import query as colquery
 from repro.columnar.table import Table
 from repro.core.adv import AugmentedDictionary
 from repro.core.feature_spec import FeatureSet
+from repro import kernels
 from repro.kernels.adv_gather import ops as adv_ops
 from repro.kernels.bitunpack.kernel import tpu_width
 from repro.kernels.predicate_scan import ops as scan_ops
@@ -822,6 +823,12 @@ class FeatureExecutor:
         # value predicate matches). Unconditional: int32 plans still reach
         # _compiled_pred to raise the packed-plan guard.
         self._pred_cache: dict = {}
+        if self.kernel_active:
+            plan.fused_tables()        # build eagerly, not inside the jit trace
+        if use_kernel and self.packed and (
+                device or jax.devices()[0]).platform == "tpu":
+            # refuse before any word stream takes HBM
+            self._require_compiled_kernels(device or jax.devices()[0])
         if self.packed:
             # ONE flat device-resident stream holds every column's words
             # (column c's start at _word_offs[c]); range windows are
@@ -839,8 +846,48 @@ class FeatureExecutor:
             # launch still self-commits through the same call)
             if commit:
                 self.ensure_range_capacity(plan.n_rows)
+
+    def _require_compiled_kernels(self, device) -> None:
+        """Compile, for ``device`` and this plan's widths, every Pallas
+        kernel a ``use_kernel=True`` packed executor launches; raise
+        :class:`repro.kernels.KernelRefused` naming each one the compiler
+        refuses, with its reason. Nothing runs and nothing is placed."""
+        plan = self.plan
+        n = plan.n_rows
+        dbs = tuple(plan.device_bits)
+        cap = _pad32(n)
+        offs = tuple(itertools.accumulate(
+            (cap * db // 32 for db in dbs[:-1]), initial=0))
+        on_dev = jax.sharding.SingleDeviceSharding(device)
+
+        def shape(shp, dtype):
+            return jax.ShapeDtypeStruct(shp, dtype, sharding=on_dev)
+
+        words = shape((sum(cap * db // 32 for db in dbs),), jnp.uint32)
+        k0 = plan.plans[0].cardinality
+        probes = {
+            "predicate_scan": (lambda w: scan_ops.predicate_scan(
+                w, offs, dbs, (scan_ops.ScanTerm(0, 0, 0, 0),
+                               scan_ops.ScanTerm(0, 1, lut=np.ones(k0))),
+                n, interpret=False), words),
+            "masked_hist": (lambda w, m: scan_ops.masked_counts(
+                w, 0, dbs[0], m, k0, n, use_kernel=True, interpret=False),
+                words, shape((n,), jnp.bool_)),
+        }
         if self.kernel_active:
-            plan.fused_tables()        # build eagerly, not inside the jit trace
+            f = plan.fused_tables()
+            probes["adv_gather_packed_rows"] = (
+                lambda w, t, ro, cl, r: adv_ops.adv_gather_packed_rows(
+                    w, offs, dbs, t, ro, cl, r, f.out_dim, bn=f.bn, bk=f.bk,
+                    interpret=False),
+                words, shape(f.table.shape, f.table.dtype),
+                shape(f.row_offsets.shape, jnp.int32),
+                shape(f.card_limits.shape, jnp.int32),
+                shape((f.bn,), jnp.int32))
+        refused = {name: why for name, (fn, *args) in probes.items()
+                   if (why := kernels.compile_refusal(fn, *args))}
+        if refused:
+            raise kernels.KernelRefused(refused)
 
     @property
     def kernel_active(self) -> bool:

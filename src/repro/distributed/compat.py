@@ -1,27 +1,15 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""``shard_map`` with the replication-check flag under its current name.
 
-``shard_map`` graduated from ``jax.experimental.shard_map`` to ``jax.shard_map``
-and its replication-check kwarg was renamed ``check_rep`` -> ``check_vma``
-along the way. This wrapper accepts the new-style ``check_vma`` name and
-translates to whatever the installed jax understands.
+The installed jax exposes ``jax.shard_map`` with a ``check_vma`` keyword;
+this wrapper keeps call sites to one import and makes the flag optional.
 """
 from __future__ import annotations
 
-import inspect
-
-try:                                    # jax >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = set(inspect.signature(_shard_map).parameters)
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool | None = None):
     kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
     if check_vma is not None:
-        if "check_vma" in _PARAMS:
-            kwargs["check_vma"] = check_vma
-        elif "check_rep" in _PARAMS:
-            kwargs["check_rep"] = check_vma
+        kwargs["check_vma"] = check_vma
     return _shard_map(f, **kwargs)

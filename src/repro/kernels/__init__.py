@@ -12,6 +12,43 @@ kernel here is a bandwidth-shaped kernel around the dictionary:
 - ``hist``       — count-metadata build (per-block histograms, paper §6.2)
 
 Each subpackage: ``kernel.py`` (pl.pallas_call + BlockSpec), ``ops.py``
-(jit'd public wrapper), ``ref.py`` (pure-jnp oracle). Tests sweep shapes x
-dtypes against the oracle with ``interpret=True`` (this container is CPU).
+(jit'd public wrapper), ``ref.py`` (pure-jnp oracle). Every entry point
+takes ``interpret=None``, resolved by :func:`resolve_interpret`: the Pallas
+interpreter runs only on the CPU backend, so a TPU always runs the compiled
+kernel. :func:`compile_refusal` asks the compiler whether a kernel lowers
+for a device without running it.
 """
+from __future__ import annotations
+
+import jax
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret`` as given, or — for ``None`` — True exactly when JAX's
+    default backend is the CPU (where Pallas can only interpret)."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
+
+
+class KernelRefused(RuntimeError):
+    """The compiler refused one or more kernels for the serving device.
+
+    ``refused`` maps each kernel's name to the compiler's own message."""
+
+    def __init__(self, refused: dict[str, str]):
+        self.refused = dict(refused)
+        super().__init__("; ".join(f"{name} does not compile for this "
+                                   f"device: {why}"
+                                   for name, why in self.refused.items()))
+
+
+def compile_refusal(fn, *args) -> str | None:
+    """Lower and compile ``jax.jit(fn)`` for ``args`` (``ShapeDtypeStruct``s
+    whose shardings name the target device); nothing runs. Returns None
+    when the compiler accepts it, else its error as ``"Type: message"``."""
+    try:
+        jax.jit(fn).lower(*args).compile()
+    except Exception as e:   # any refusal (Mosaic, XLA, unimplemented lowering)
+        return f"{type(e).__name__}: {e}"
+    return None

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _adv_gather_kernel(codes_ref, table_ref, out_ref, *, bk: int):
     k = pl.program_id(1)
@@ -122,8 +124,10 @@ def _adv_gather_packed_rows_kernel(rows_ref, words_ref, row_off_ref,
     never exist, and the only per-launch host->device traffic is the index
     vector itself (4B x N, independent of column count).
 
-    The in-kernel word gather (``jnp.take``) is exact in interpret mode; a
-    real-TPU lowering needs a DMA-based gather (ROADMAP: validate on TPU).
+    The in-kernel word gather (``jnp.take``) is exact in interpret mode;
+    Mosaic refuses it for a TPU ("Only 2D gather is supported"), so a TPU
+    executor raises ``KernelRefused`` for this kernel until it gathers by
+    DMA (ROADMAP S2).
     """
     k = pl.program_id(1)
 
@@ -159,7 +163,7 @@ def adv_gather_packed_rows_pallas(rows: jnp.ndarray, words: jnp.ndarray,
                                   table: jnp.ndarray, n: int, bn: int = 256,
                                   bk: int = 512, dbs: tuple = (),
                                   word_offs: tuple = (),
-                                  interpret: bool = True) -> jnp.ndarray:
+                                  interpret: bool | None = None) -> jnp.ndarray:
     """rows (n,) int32 arbitrary row indices, words (W,) uint32 resident
     streams, table (K_total, F_total) block-diagonal -> (n, F_total).
 
@@ -183,7 +187,7 @@ def adv_gather_packed_rows_pallas(rows: jnp.ndarray, words: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows.reshape(1, n), words.reshape(1, w), row_offsets, card_limits,
       table)
 
@@ -195,7 +199,7 @@ def adv_gather_packed_pallas(words: jnp.ndarray, row_offsets: jnp.ndarray,
                              card_limits: jnp.ndarray, table: jnp.ndarray,
                              n: int, bn: int = 256, bk: int = 512,
                              dbs: tuple = (), word_offs: tuple = (),
-                             interpret: bool = True) -> jnp.ndarray:
+                             interpret: bool | None = None) -> jnp.ndarray:
     """words (W,) uint32 (all columns' device-width streams concatenated),
     table (K_total, F_total) block-diagonal -> (n, F_total) features.
 
@@ -221,7 +225,7 @@ def adv_gather_packed_pallas(words: jnp.ndarray, row_offsets: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words.reshape(1, w), row_offsets, card_limits, table)
 
 
@@ -229,7 +233,7 @@ def adv_gather_packed_pallas(words: jnp.ndarray, row_offsets: jnp.ndarray,
                    static_argnames=("bn", "bk", "interpret"))
 def adv_gather_multi_pallas(codes: jnp.ndarray, table: jnp.ndarray,
                             bn: int = 256, bk: int = 512,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: bool | None = None) -> jnp.ndarray:
     """codes (C, N) int32 pre-offset into block-diagonal rows, table
     (K_total, F_total) -> (N, F_total) concatenated features.
 
@@ -248,7 +252,7 @@ def adv_gather_multi_pallas(codes: jnp.ndarray, table: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes, table)
 
 
@@ -256,7 +260,7 @@ def adv_gather_multi_pallas(codes: jnp.ndarray, table: jnp.ndarray,
                    static_argnames=("bn", "bk", "interpret"))
 def adv_gather_pallas(codes: jnp.ndarray, table: jnp.ndarray,
                       bn: int = 256, bk: int = 512,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     """codes (N,) int32, table (K, F) float -> (N, F).
 
     Preconditions (enforced by ops.py): N % bn == 0, K % bk == 0,
@@ -274,5 +278,5 @@ def adv_gather_pallas(codes: jnp.ndarray, table: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f), table.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes.reshape(1, n), table)

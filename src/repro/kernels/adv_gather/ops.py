@@ -57,7 +57,7 @@ def _pad_to(x: int, m: int) -> int:
 
 def adv_gather(table: jnp.ndarray, codes: jnp.ndarray,
                bn: int = 256, bk: int = 512,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool | None = None) -> jnp.ndarray:
     """Gather ADV rows for a code vector of any shape; returns (*codes.shape, F)."""
     shape = codes.shape
     flat = codes.reshape(-1).astype(jnp.int32)
@@ -163,7 +163,7 @@ def gather_fused_parts(table: jnp.ndarray, row_offsets: jnp.ndarray,
                        codes: jnp.ndarray, out_dim: int,
                        card_limits: jnp.ndarray | None = None,
                        bn: int = 256, bk: int = 512,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """Functional core of :func:`adv_gather_fused`.
 
     Takes the super-table and offsets as plain arrays so callers can jit
@@ -187,7 +187,7 @@ def gather_fused_parts(table: jnp.ndarray, row_offsets: jnp.ndarray,
 
 
 def adv_gather_fused(fused: FusedTables, codes: jnp.ndarray,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool | None = None) -> jnp.ndarray:
     """codes (C, N) int32 (codes[c] indexes source table c) -> (N, ΣF)."""
     c_count = codes.shape[0]
     if c_count != fused.n_tables:
@@ -221,7 +221,7 @@ packed_kernel_fits = fused_kernel_fits
 def adv_gather_packed(windows, dbs, fused_table: jnp.ndarray,
                       row_offsets: jnp.ndarray, card_limits: jnp.ndarray,
                       n: int, out_dim: int, bn: int = 256, bk: int = 512,
-                      bw: int = 512, interpret: bool = True) -> jnp.ndarray:
+                      bw: int = 512, interpret: bool | None = None) -> jnp.ndarray:
     """Fused unpack+gather: packed word windows -> (n, out_dim) features.
 
     ``windows[c]`` holds column c's device-width (``dbs[c]`` | 32) packed
@@ -260,7 +260,7 @@ def adv_gather_packed_rows(flat_words: jnp.ndarray, word_offs, dbs,
                            row_offsets: jnp.ndarray,
                            card_limits: jnp.ndarray, rows: jnp.ndarray,
                            out_dim: int, bn: int = 256, bk: int = 512,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool | None = None) -> jnp.ndarray:
     """Random-row fused unpack+gather: row indices -> (len(rows), out_dim).
 
     ``flat_words`` concatenates every column's resident device-width word
@@ -347,7 +347,7 @@ PACKED_BLOCK_CANDIDATES = ((128, 512, 512), (256, 256, 512), (256, 512, 512),
 
 def autotune_packed(windows, dbs, fused: FusedTables, n: int,
                     candidates=PACKED_BLOCK_CANDIDATES, repeats: int = 3,
-                    interpret: bool = True) -> tuple[int, int, int]:
+                    interpret: bool | None = None) -> tuple[int, int, int]:
     """Sweep (bn, bk, bw) for the fused packed kernel; return the fastest.
 
     Invalid candidates (bn not word-aligned, bk that does not tile the
@@ -386,7 +386,7 @@ _ROWS_TUNE_CACHE: dict[tuple, tuple[int, int]] = {}
 def autotune_packed_rows(flat_words, word_offs, dbs, fused: FusedTables,
                          n: int, candidates=FUSED_BLOCK_CANDIDATES,
                          repeats: int = 3,
-                         interpret: bool = True) -> tuple[int, int]:
+                         interpret: bool | None = None) -> tuple[int, int]:
     """Sweep (bn, bk) for the random-row packed kernel; return the fastest.
 
     Times :func:`adv_gather_packed_rows` ITSELF (its in-kernel word gather
@@ -420,7 +420,7 @@ def autotune_packed_rows(flat_words, word_offs, dbs, fused: FusedTables,
 
 def autotune_fused(codes: jnp.ndarray, fused: FusedTables, n: int,
                    candidates=FUSED_BLOCK_CANDIDATES, repeats: int = 3,
-                   interpret: bool = True) -> tuple[int, int]:
+                   interpret: bool | None = None) -> tuple[int, int]:
     """Sweep (bn, bk) for the int32 fused gather-concat kernel (the packed
     path's autotune, ported per the ROADMAP item); return the fastest.
 

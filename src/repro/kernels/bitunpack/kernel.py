@@ -8,8 +8,10 @@ bounded ≤2x packing loss (e.g. 6->8 bits) for a fully lane-parallel unpack:
 
     out.reshape(BW, S)[w, s] = (words[w] >> (s*b)) & mask,  S = 32/b
 
-Each grid step unpacks one (1, BW) word tile into an (S, BW)-transposed code
-tile, all in VREGs. Host storage (columnar/bitpack.py) keeps exact widths.
+Each grid step unpacks one (1, BW) word tile into an (S, BW) subfield-major
+code tile, all in VREGs; the word-major interleave runs outside the kernel
+(ops.py; Mosaic refuses the in-kernel (BW, S) -> (1, BW*S) shape cast).
+Host storage (columnar/bitpack.py) keeps exact widths.
 """
 from __future__ import annotations
 
@@ -18,6 +20,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 DIVISOR_WIDTHS = (1, 2, 4, 8, 16, 32)
 
@@ -39,15 +43,15 @@ def _bitunpack_kernel(words_ref, out_ref, *, bits: int):
     fields = (words.astype(jnp.uint32) >> shifts)
     if bits < 32:
         fields = fields & jnp.uint32((1 << bits) - 1)
-    # code order is word-major, subfield-minor -> transpose to (BW, S)
-    out_ref[...] = fields.T.reshape(1, bw * s).astype(jnp.int32)
+    out_ref[...] = fields.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bw", "interpret"))
 def bitunpack_pallas(words: jnp.ndarray, bits: int, bw: int = 512,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool | None = None) -> jnp.ndarray:
     """words (W,) uint32 packed at ``bits`` (must divide 32, W % bw == 0)
-    -> (W * 32/bits,) int32 codes."""
+    -> (32/bits, W) int32 subfield-major codes: ``[j, w]`` is the code of
+    row ``w * 32/bits + j``."""
     if 32 % bits:
         raise ValueError(f"device path needs bits | 32, got {bits} "
                          "(use tpu_width + ops.repack)")
@@ -58,7 +62,7 @@ def bitunpack_pallas(words: jnp.ndarray, bits: int, bw: int = 512,
         functools.partial(_bitunpack_kernel, bits=bits),
         grid=grid,
         in_specs=[pl.BlockSpec((1, bw), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, bw * s), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, w * s), jnp.int32),
-        interpret=interpret,
-    )(words.reshape(1, w)).reshape(w * s)
+        out_specs=pl.BlockSpec((s, bw), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((s, w), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(words.reshape(1, w))

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _hist_kernel(codes_ref, out_ref, *, bk: int):
     j = pl.program_id(1)
@@ -33,7 +35,7 @@ def _hist_kernel(codes_ref, out_ref, *, bk: int):
 
 @functools.partial(jax.jit, static_argnames=("k", "bn", "bk", "interpret"))
 def hist_pallas(codes: jnp.ndarray, k: int, bn: int = 1024, bk: int = 512,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """codes (N,) int32 in [0, k) -> counts (k,) int32.
 
     Preconditions (ops.py): N % bn == 0, k % bk == 0.
@@ -46,7 +48,7 @@ def hist_pallas(codes: jnp.ndarray, k: int, bn: int = 1024, bk: int = 512,
         in_specs=[pl.BlockSpec((1, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((1, bk), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, k), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes.reshape(1, n)).reshape(k)
 
 
@@ -69,7 +71,7 @@ def _masked_hist_kernel(codes_ref, mask_ref, out_ref, *, bk: int):
 @functools.partial(jax.jit, static_argnames=("k", "bn", "bk", "interpret"))
 def masked_hist_pallas(codes: jnp.ndarray, mask: jnp.ndarray, k: int,
                        bn: int = 1024, bk: int = 512,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """codes (N,) int32 in [0, k), mask (N,) int32 -> counts (k,) int32 of
     the codes whose mask lane is nonzero — the predicate-pushdown aggregate
     core: the count tile stays resident while code AND selection-bitmap
@@ -86,5 +88,5 @@ def masked_hist_pallas(codes: jnp.ndarray, mask: jnp.ndarray, k: int,
                   pl.BlockSpec((1, bn), lambda i, j: (0, j))],
         out_specs=pl.BlockSpec((1, bk), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, k), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes.reshape(1, n), mask.reshape(1, n)).reshape(k)

@@ -11,7 +11,7 @@ def _pad_to(x: int, m: int) -> int:
 
 
 def hist(codes: jnp.ndarray, k: int, bn: int = 1024, bk: int = 512,
-         interpret: bool = True) -> jnp.ndarray:
+         interpret: bool | None = None) -> jnp.ndarray:
     """Count occurrences of each code in [0, k)."""
     flat = codes.reshape(-1).astype(jnp.int32)
     n = flat.shape[0]
@@ -24,7 +24,7 @@ def hist(codes: jnp.ndarray, k: int, bn: int = 1024, bk: int = 512,
 
 def masked_hist(codes: jnp.ndarray, mask: jnp.ndarray, k: int,
                 bn: int = 1024, bk: int = 512,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """Count occurrences of each code in [0, k) where ``mask`` is set —
     the histogram a predicate-pushdown aggregate runs over a selection
     bitmap instead of the whole column."""

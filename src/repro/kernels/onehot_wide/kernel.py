@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _onehot_wide_kernel(codes_ref, w_ref, out_ref, *, bk: int):
     c = pl.program_id(1)
@@ -38,7 +40,7 @@ def _onehot_wide_kernel(codes_ref, w_ref, out_ref, *, bk: int):
 @functools.partial(jax.jit, static_argnames=("bn", "bk", "interpret"))
 def onehot_wide_pallas(codes: jnp.ndarray, w: jnp.ndarray,
                        bn: int = 256, bk: int = 512,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """codes (C, N) int32; w (C, K, F) float -> out (N, F).
 
     Preconditions (ops.py): N % bn == 0, K % bk == 0.
@@ -55,5 +57,5 @@ def onehot_wide_pallas(codes: jnp.ndarray, w: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, f), lambda i, c, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, f), w.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes, w)

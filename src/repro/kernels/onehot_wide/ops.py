@@ -13,7 +13,7 @@ def _pad_to(x: int, m: int) -> int:
 
 def onehot_wide(codes: jnp.ndarray, w: jnp.ndarray,
                 bn: int = 256, bk: int = 512,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool | None = None) -> jnp.ndarray:
     """codes (C, N) int32, w (C, K, F) -> (N, F) wide-layer output."""
     c, n = codes.shape
     _, k, f = w.shape

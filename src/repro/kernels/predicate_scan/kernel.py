@@ -18,8 +18,8 @@ kernels' column loops):
   Equality and (on sorted dictionaries) value ranges compile to this.
 - ``kind 1`` — arbitrary code set via a K-entry LUT probe: IN-sets and
   unsorted-dictionary ranges. The probe (``jnp.take``) is exact in
-  interpret mode; a real-TPU lowering needs a DMA-based gather — the same
-  ROADMAP caveat as the random-row packed gather kernel.
+  interpret mode; Mosaic refuses it for a TPU ("Only 2D gather is
+  supported"), like the random-row packed gather kernel (ROADMAP S2).
 
 Grid: (N/BN,). The whole word stream stays resident across grid steps (it
 is 32/db x smaller than the codes it encodes); range bounds ride in a tiny
@@ -32,6 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import resolve_interpret
 
 
 def _predicate_scan_kernel(words_ref, bounds_ref, lut_ref, out_ref, *,
@@ -75,7 +77,7 @@ def predicate_scan_pallas(words: jnp.ndarray, bounds: jnp.ndarray,
                           dbs: tuple = (), word_offs: tuple = (),
                           lut_offs: tuple = (), lut_lens: tuple = (),
                           combine: str = "and",
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool | None = None) -> jnp.ndarray:
     """words (W,) uint32 concatenated streams, bounds (T, 2) int32 range
     rows, lut (L,) int32 concatenated LUTs -> (n,) int32 selection bitmap.
 
@@ -99,5 +101,5 @@ def predicate_scan_pallas(words: jnp.ndarray, bounds: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((1, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words.reshape(1, w), bounds, lut.reshape(1, l)).reshape(n)

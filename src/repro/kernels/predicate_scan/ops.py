@@ -33,6 +33,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.bitunpack.ops import (concat_row_blocks, map_row_blocks,
+                                         rows_of, unpack_rows)
 from repro.kernels.predicate_scan.kernel import predicate_scan_pallas
 from repro.kernels.hist.ops import masked_hist
 
@@ -95,7 +97,7 @@ def _pack_terms(terms, dbs):
 
 def predicate_scan(flat_words: jnp.ndarray, word_offs, dbs, terms, n: int,
                    combine: str = "and", bn: int = 1024,
-                   interpret: bool = True) -> jnp.ndarray:
+                   interpret: bool | None = None) -> jnp.ndarray:
     """Fused Pallas scan: resident flat stream + compiled terms -> (n,)
     bool selection mask.
 
@@ -136,36 +138,31 @@ def predicate_scan(flat_words: jnp.ndarray, word_offs, dbs, terms, n: int,
 
 def _scan_body(flat_words, bounds, flat_lut, *, word_offs, dbs, n, cols,
                kinds, lut_offs, lut_lens, combine):
-    """XLA split scan: whole-stream broadcast unpack + vectorized terms.
+    """XLA split scan: broadcast unpack + vectorized terms per row block.
 
-    Few large fused ops (CPU per-op overhead dominates tile loops), one
-    unpack per referenced column even when several terms share it. The
-    resident stream covers _pad32(n) rows per column (the executor's
-    capacity quantum), so the static slices never cross column segments.
+    Few large fused ops per block (CPU per-op overhead dominates tile
+    loops), one unpack per referenced column even when several terms share
+    it; the blocks (:func:`map_row_blocks`) bound the padded intermediates
+    XLA:TPU would otherwise lay out over the whole column.
     """
-    acc = None
-    codes_cache = {}
-    for t, c in enumerate(cols):
-        codes = codes_cache.get(c)
-        if codes is None:
-            db = dbs[c]
-            s = 32 // db
-            nw = (n + s - 1) // s
-            w = flat_words[word_offs[c]:word_offs[c] + nw]
-            shifts = (jnp.arange(s, dtype=jnp.uint32) * jnp.uint32(db))
-            fields = w[:, None] >> shifts[None, :]          # (NW, S)
-            if db < 32:
-                fields = fields & jnp.uint32((1 << db) - 1)
-            codes = fields.reshape(-1)[:n].astype(jnp.int32)
-            codes_cache[c] = codes
-        if kinds[t] == 0:
-            m = (codes >= bounds[t, 0]) & (codes <= bounds[t, 1])
-        else:
-            idx = jnp.minimum(codes, lut_lens[t] - 1)
-            m = jnp.take(flat_lut, lut_offs[t] + idx, mode="clip") != 0
-        acc = m if acc is None else \
-            ((acc & m) if combine == "and" else (acc | m))
-    return acc
+    def block(start, size):
+        acc = None
+        codes_cache = {}
+        for t, c in enumerate(cols):
+            codes = codes_cache.get(c)
+            if codes is None:
+                codes = codes_cache[c] = unpack_rows(
+                    flat_words, word_offs[c], dbs[c], start, size)
+            if kinds[t] == 0:
+                m = (codes >= bounds[t, 0]) & (codes <= bounds[t, 1])
+            else:
+                idx = jnp.minimum(codes, lut_lens[t] - 1)
+                m = jnp.take(flat_lut, lut_offs[t] + idx, mode="clip") != 0
+            acc = m if acc is None else \
+                ((acc & m) if combine == "and" else (acc | m))
+        return acc
+
+    return concat_row_blocks(*map_row_blocks(block, n), n)
 
 
 _SCAN_STATICS = ("word_offs", "dbs", "n", "cols", "kinds", "lut_offs",
@@ -246,24 +243,22 @@ def compact_rows(mask: jnp.ndarray, cap: int, fill: int = 0) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("off", "db", "n", "k"))
 def _masked_counts_split(flat_words, mask, *, off, db, n, k):
-    """XLA masked histogram against the resident words: one broadcast
-    unpack + one segment-sum scatter-add."""
-    s = 32 // db
-    nw = (n + s - 1) // s
-    w = flat_words[off:off + nw]
-    shifts = (jnp.arange(s, dtype=jnp.uint32) * jnp.uint32(db))
-    fields = w[:, None] >> shifts[None, :]
-    if db < 32:
-        fields = fields & jnp.uint32((1 << db) - 1)
-    codes = fields.reshape(-1)[:n].astype(jnp.int32)
-    hits = mask.astype(jnp.int32)
-    return jnp.zeros(k, jnp.int32).at[codes].add(hits, mode="drop")
+    """XLA masked histogram against the resident words: a broadcast unpack
+    + a segment-sum scatter-add per row block, summed over the blocks."""
+    def block(start, size):
+        codes = unpack_rows(flat_words, off, db, start, size)
+        hits = rows_of(mask, start, size).astype(jnp.int32)
+        return jnp.zeros(k, jnp.int32).at[codes].add(hits, mode="drop")
+
+    full, tail = map_row_blocks(block, n)
+    counts = jnp.zeros(k, jnp.int32) if tail is None else tail
+    return counts if full is None else counts + full.sum(axis=0)
 
 
 def masked_counts(flat_words: jnp.ndarray, off: int, db: int,
                   mask: jnp.ndarray, k: int, n: int,
                   use_kernel: bool = False,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool | None = None) -> jnp.ndarray:
     """Masked GROUP BY over a resident column: (k,) int32 per-code counts
     of the rows where ``mask`` is set.
 
@@ -276,13 +271,8 @@ def masked_counts(flat_words: jnp.ndarray, off: int, db: int,
     """
     mask = jnp.asarray(mask).reshape(-1)[:n]
     if use_kernel:
-        s = 32 // db
-        nw = (n + s - 1) // s
-        w = jnp.asarray(flat_words, jnp.uint32)[off:off + nw]
-        shifts = (jnp.arange(s, dtype=jnp.uint32) * jnp.uint32(db))
-        fields = w[:, None] >> shifts[None, :]
-        if db < 32:
-            fields = fields & jnp.uint32((1 << db) - 1)
-        codes = fields.reshape(-1)[:n].astype(jnp.int32)
+        codes = concat_row_blocks(*map_row_blocks(
+            lambda start, size: unpack_rows(flat_words, off, db, start,
+                                            size), n), n)
         return masked_hist(codes, mask, k, interpret=interpret)
     return _masked_counts_split(flat_words, mask, off=off, db=db, n=n, k=k)

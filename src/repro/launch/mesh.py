@@ -6,6 +6,7 @@ jax device state (smoke tests see 1 device; only dryrun.py forces 512).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,7 +15,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     data parallelism over the slower inter-pod links."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_probe_mesh(n_devices: int | None = None):
@@ -25,4 +26,5 @@ def make_probe_mesh(n_devices: int | None = None):
         if n % m == 0:
             model = m
             break
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

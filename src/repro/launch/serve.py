@@ -12,6 +12,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.launch.train import preset_config
 from repro.models import lm
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = preset_config(get_config(args.arch), args.preset)
     params = lm.init_params(cfg, jax.random.PRNGKey(args.seed))

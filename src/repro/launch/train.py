@@ -19,6 +19,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.data import TokenStore, synthetic_corpus, token_batches
 from repro.models import lm
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = preset_config(get_config(args.arch), args.preset)
     print(f"arch={cfg.name} family={cfg.family} params~"

@@ -1,21 +1,4 @@
-"""Suite-wide setup: CPU-only JAX by default, hypothesis fallback shim.
-
-If the real ``hypothesis`` is installed it is used untouched; otherwise the
-deterministic shim in ``tests/_hypothesis_compat.py`` is registered under the
-``hypothesis`` module names so the 6 property-test modules still collect and
-run in offline environments.
-"""
+"""Suite-wide setup: JAX runs on the CPU backend unless told otherwise."""
 import os
-import pathlib
-import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
-    import _hypothesis_compat
-
-    sys.modules["hypothesis"] = _hypothesis_compat
-    sys.modules["hypothesis.strategies"] = _hypothesis_compat.strategies

@@ -21,6 +21,9 @@ def run_sub(code: str, devices: int = 8, timeout: int = 540) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={devices}"
         import sys
         sys.path.insert(0, {os.path.join(REPO, 'src')!r})
+        from jax.sharding import AxisType
+        AUTO1 = (AxisType.Auto,)
+        AUTO2 = (AxisType.Auto,) * 2
     """)
     r = subprocess.run([sys.executable, "-c", prelude + textwrap.dedent(code)],
                        capture_output=True, text=True, timeout=timeout)
@@ -38,7 +41,7 @@ def test_pjit_train_step_descends_on_mesh():
         from repro.train.optimizer import OptConfig
         from repro.train.trainer import make_train_step, TrainConfig
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
         cfg = dataclasses.replace(reduced(get_config("qwen2-7b")),
                                   d_model=64, vocab=256,
                                   vocab_pad_multiple=64)
@@ -80,7 +83,7 @@ def test_param_specs_divisible_everywhere():
         from repro.distributed import sharding as shd
         from repro.models import lm
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=AUTO2)
         sizes = dict(mesh.shape)
         for arch in ARCH_IDS:
             cfg = get_config(arch)
@@ -106,7 +109,7 @@ def test_pipeline_parallel_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline_par import (pipelined_forward,
                                                     bubble_fraction)
-        mesh = jax.make_mesh((4,), ("stage",))
+        mesh = jax.make_mesh((4,), ("stage",), axis_types=AUTO1)
         rng = np.random.default_rng(0)
         S, M, MB, D = 4, 6, 8, 16
         w = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)
@@ -135,7 +138,7 @@ def test_compressed_psum_error_feedback():
         from repro.distributed.compression import (psum_compressed,
                                                    compression_ratio)
         from repro.distributed.compat import shard_map
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = jax.make_mesh((4,), ("pod",), axis_types=AUTO1)
         rng = np.random.default_rng(0)
         g_all = jnp.asarray(rng.standard_normal((4, 64, 32)), jnp.float32)
 
@@ -169,7 +172,7 @@ def test_elastic_remesh_checkpoint_restore():
         from repro.train import checkpoint as ck
         from repro.train.fault import plan_elastic_mesh
 
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = jax.make_mesh((4, 2), ("data", "model"), axis_types=AUTO2)
         tree = {"w": jax.device_put(
             jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
             NamedSharding(mesh1, P("data", "model")))}
@@ -179,7 +182,8 @@ def test_elastic_remesh_checkpoint_restore():
         plan = plan_elastic_mesh(4, model_parallel=2)
         assert plan.shape == (2, 2)
         mesh2 = jax.make_mesh(plan.shape, plan.axes,
-                              devices=np.array(jax.devices()[:4]))
+                              devices=np.array(jax.devices()[:4]),
+                              axis_types=AUTO2)
         sh2 = {"w": NamedSharding(mesh2, P("data", "model"))}
         step, restored, _ = ck.restore_latest(d, tree, shardings=sh2)
         assert step == 5

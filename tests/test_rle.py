@@ -67,7 +67,7 @@ def test_adjacent_encoded_values_differ(runs):
         assert np.all(values[1:] != values[:-1])
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(_runs(bits=6, max_runs=8))
 def test_device_decode_matches_host(runs):
     codes = _codes_from_runs(runs)
