@@ -444,12 +444,12 @@ def _chaos_serve_comparison() -> None:
     loops = [clean_loop, chaos_loop]
     for loop in loops:
         loop()                                             # compile each
-    svc_clean.latencies.clear()
-    svc_chaos.latencies.clear()
+    svc_clean.reset_latency_window()
+    svc_chaos.reset_latency_window()
     failovers0 = svc_chaos.stats["failovers"]
     clean_s, chaos_s = interleaved_best(loops, repeats=MIN_REPEATS)
-    p99_clean = float(np.percentile(np.array(svc_clean.latencies), 99))
-    p99_chaos = float(np.percentile(np.array(svc_chaos.latencies), 99))
+    p99_clean = svc_clean.latency_percentile(99)
+    p99_chaos = svc_chaos.latency_percentile(99)
     st = svc_chaos.throughput_stats(chaos_s)
     emit("serve/feature_service_chaos_clean", clean_s / n_req * 1e6,
          f"rows_per_s={rows/clean_s:.0f};p99_ms={p99_clean*1e3:.3f};"
@@ -536,11 +536,11 @@ def _hedged_serve_comparison() -> None:
     loops = [plain_loop, hedge_loop]
     for loop in loops:
         loop()                       # compile + train the EWMA past warmup
-    svc_hedge.latencies.clear()
-    svc_plain.latencies.clear()
+    svc_hedge.reset_latency_window()
+    svc_plain.reset_latency_window()
     plain_s, hedge_s = interleaved_best(loops, repeats=MIN_REPEATS)
-    p99_plain = float(np.percentile(np.array(svc_plain.latencies), 99))
-    p99_hedge = float(np.percentile(np.array(svc_hedge.latencies), 99))
+    p99_plain = svc_plain.latency_percentile(99)
+    p99_hedge = svc_hedge.latency_percentile(99)
     st = svc_hedge.throughput_stats(hedge_s)
     emit("serve/feature_service_hedged_nohedge", plain_s / n_req * 1e6,
          f"rows_per_s={rows/plain_s:.0f};p99_ms={p99_plain*1e3:.3f};"
@@ -689,11 +689,11 @@ def _tiered_serve_comparison() -> None:
         for r in reqs[:50]:
             sm.submit(r)
         sm.drain()                 # warm the pool + caches
-        sm.latencies.clear()
+        sm.reset_latency_window()
         for r in reqs:
             sm.submit(r)
         sm.drain()
-        p99[workers] = float(np.percentile(np.array(sm.latencies), 99))
+        p99[workers] = sm.latency_percentile(99)
         assert sm.stats["promotions"] == 0     # nothing ever fits
     emit("serve/feature_service_tiered_miss_p99",
          p99[4] * 1e6,

@@ -84,6 +84,7 @@ from repro import kernels
 from repro.kernels.adv_gather import ops as adv_ops
 from repro.kernels.bitunpack.kernel import tpu_width
 from repro.kernels.predicate_scan import ops as scan_ops
+from repro.spans import span
 
 
 def _pad32(n: int) -> int:
@@ -1295,9 +1296,14 @@ class FeatureExecutor:
     def agg_where(self, pred, column: str, agg: str = "count") -> float:
         """Masked count/sum/mean of ``column`` under ``pred`` — K-entry
         dictionary tail work on top of the device masked histogram."""
-        counts = self._masked_counts_from(column, self.predicate_mask(pred))
-        d = self.plan.augmented[column].dictionary
-        return _agg_from_counts(d, np.asarray(counts), agg)
+        with span("query.agg_where", column=column, agg=agg):
+            counts = self._masked_counts_from(column,
+                                              self.predicate_mask(pred))
+            with span("query.fetch") as fetch:
+                counts = np.asarray(counts)
+                fetch.set_metadata(nbytes=counts.nbytes)
+            d = self.plan.augmented[column].dictionary
+            return _agg_from_counts(d, counts, agg)
 
     # -- single batch -------------------------------------------------------------
     def slice_codes(self, row_idx: np.ndarray) -> np.ndarray:
@@ -1781,11 +1787,14 @@ class ShardedFeatureExecutor:
         return d.values, counts.astype(np.int64)
 
     def agg_where(self, pred, column: str, agg: str = "count") -> float:
-        futs = [ex._masked_counts_from(column, mask)
-                for _, ex, mask in self._shard_masks(pred)]
-        counts = np.sum([np.asarray(f) for f in futs], axis=0)
-        d = self.plan.augmented[column].dictionary
-        return _agg_from_counts(d, counts, agg)
+        with span("query.agg_where", column=column, agg=agg):
+            futs = [ex._masked_counts_from(column, mask)
+                    for _, ex, mask in self._shard_masks(pred)]
+            with span("query.fetch") as fetch:
+                parts = [np.asarray(f) for f in futs]
+                fetch.set_metadata(nbytes=sum(a.nbytes for a in parts))
+            d = self.plan.augmented[column].dictionary
+            return _agg_from_counts(d, np.sum(parts, axis=0), agg)
 
     def batch(self, row_idx: np.ndarray) -> jnp.ndarray:
         """Routed featurization of arbitrary rows, request order preserved.

@@ -19,12 +19,11 @@ needs to treat one tenant's work differently from another's —
   work, anything past that rejected with a typed :class:`Overloaded`).
 
 :class:`LatencyHistogram` is the streaming log-bucketed latency record
-behind the per-class SLO gates — unlike the bench-compat
-``FeatureService.latencies`` deque (a sliding 8192-sample window whose
-``np.percentile`` silently reports the p99 of only the most RECENT
-tickets on long runs), the histogram sees every completed ticket at a
-fixed ~10% relative resolution, so its percentiles are unbiased however
-long the service has been up.
+behind the per-class SLO gates and queue waits — unlike a sliding window
+of recent samples (whose percentiles report only the most RECENT tickets
+on long runs), the histogram sees every completed ticket at a fixed ~10%
+relative resolution, so its percentiles are unbiased however long the
+service has been up.
 """
 from __future__ import annotations
 

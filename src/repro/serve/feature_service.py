@@ -199,6 +199,7 @@ from repro.serve.classes import LatencyHistogram, RequestClass
 from repro.serve.faults import (DeadlineExceeded, DeviceDown, DeviceHealth,
                                 FaultInjector, FaultPolicy, ServeError,
                                 StreamBreaker)
+from repro.spans import OFF, ids, span
 from repro.train.fault import StragglerDetector
 
 DEFAULT_BUCKETS = (64, 256, 1024)
@@ -375,13 +376,9 @@ class FeatureService:
         self._stragglers = [self._new_straggler()
                             for _ in range(self._n_shards)]
         # -- latency accounting --
-        # the deque is the BENCH-COMPAT window (np.percentile over it is
-        # biased toward the most recent 8192 tickets on long runs); the
-        # histograms below see every completed ticket and back
-        # latency_percentile()/class_stats() — the SLO-gate reading.
-        # stats['latency_samples_total'] makes the window's truncation
-        # detectable (> len(latencies) means the deque wrapped)
-        self.latencies: deque[float] = deque(maxlen=8192)  # per-ticket s
+        # the histograms see every completed ticket and back
+        # latency_percentile()/class_stats() — the SLO-gate reading;
+        # stats['latency_samples_total'] counts what they cover
         self._lat_hist = LatencyHistogram()
         # -- request classes (priority pump scheduling + per-class SLOs) --
         # every service carries a 'default' class (service-wide coalesce/
@@ -396,7 +393,7 @@ class FeatureService:
         self._ticket_class: dict[int, str] = {}
         self._class_stats: dict[str, dict] = {
             name: {"requests": 0, "completed": 0, "failed": 0, "rows": 0,
-                   "hist": LatencyHistogram()}
+                   "hist": LatencyHistogram(), "queue": LatencyHistogram()}
             for name in self._classes}
         # -- adaptive shard management state --
         self.rebalance_every = rebalance_every
@@ -424,6 +421,7 @@ class FeatureService:
         self._host_workers = host_gather_workers
         self._host_pool: ThreadPoolExecutor | None = None   # lazy fan-out
         self.stats = {"requests": 0, "rows": 0, "padded_rows": 0,
+                      "launched_rows": 0,
                       "batches": 0, "launches": 0, "max_inflight": 0,
                       "latency_s_total": 0.0, "completed": 0,
                       "latency_samples_total": 0,
@@ -879,6 +877,12 @@ class FeatureService:
         priority, coalescing policy and — when ``deadline_ms`` is not
         passed — the class's default deadline.
         """
+        with span("serve.submit", klass=klass) as sp:
+            return self._submit(sp, rows, where, deadline_ms, klass)
+
+    def _submit(self, sp, rows, where, deadline_ms, klass: str) -> int:
+        """:meth:`submit` inside its ``serve.submit`` span ``sp``, which
+        gets the ticket and the request's row count."""
         rc = self._classes.get(klass)
         if rc is None:
             raise ValueError(f"unknown request class {klass!r} "
@@ -917,6 +921,7 @@ class FeatureService:
                     self._results[ticket] = np.zeros(
                         (0, self.plan.out_dim), np.float32)
                     self._cv.notify_all()
+                sp.set_metadata(ticket=ticket, rows=0)
                 return ticket
         elif rows is None:
             raise ValueError("need rows or where")
@@ -997,7 +1002,8 @@ class FeatureService:
                     if n0 == 0 or preempt or (n0 < self.coalesce <= n1):
                         self._work.notify_all()
                         break
-                return ticket
+            sp.set_metadata(ticket=ticket, rows=rows.size)
+            return ticket
 
     # -- bucketing ------------------------------------------------------------------
     def _bucket(self, n: int) -> int:
@@ -1285,6 +1291,7 @@ class FeatureService:
         """
         while True:
             with self._lock:
+                idle = None         # one pump.wait span per idle stretch
                 while True:
                     # shard-set mutations happen HERE — the pump is the
                     # only launcher, and at this point no launch or
@@ -1294,9 +1301,14 @@ class FeatureService:
                     action, arg = self._pick_action()
                     if action != "wait":
                         break
+                    if idle is None:
+                        idle = span("pump.wait")
+                        idle.__enter__()
                     if self._all_idle():
                         self._idle.notify_all()
                     self._work.wait(timeout=arg)
+                if idle is not None:
+                    idle.__exit__(None, None, None)
                 if action == "exit":
                     return
                 s = arg
@@ -1331,11 +1343,16 @@ class FeatureService:
                         self._idle.notify_all()
                     continue
                 elif action == "launch":
+                    # pump.launch runs from the take to the stats update
+                    # after the dispatch, across the lock
+                    launch = span("pump.launch", shard=s)
+                    launch.__enter__()
                     job = self._take_group(self._queues[s],
                                            time.perf_counter())
                     if not job:
                         # the whole head group was evicted (failed or
                         # deadline-expired tickets) — nothing to launch
+                        launch.__exit__(None, None, None)
                         if self._all_idle():
                             self._idle.notify_all()
                         continue
@@ -1348,7 +1365,12 @@ class FeatureService:
                         self.stats["failovers"] += 1
                 else:
                     job = None
-                    _, fl = self._inflights[s].popleft()
+                    seq, fl = self._inflights[s].popleft()
+                    retire = span("pump.retire", seq=seq)
+                    retire.__enter__()
+                    if retire is not OFF:
+                        retire.set_metadata(
+                            tickets=ids(p[0] for p in fl.parts))
                     self._pump_retiring = (s, fl)
                     self._retire_prog = 0
                 if action != "hostserve":
@@ -1370,6 +1392,7 @@ class FeatureService:
                         self._busy[s] -= 1
                         if self._all_idle():
                             self._idle.notify_all()
+                    launch.__exit__(None, None, None)
                     continue
                 with self._lock:
                     self._seq += 1
@@ -1379,6 +1402,8 @@ class FeatureService:
                     self._pump_taken = None
                     self.stats["launches"] += 1
                     self.stats["batches"] += len(parts)
+                    self.stats["launched_rows"] += \
+                        self.coalesce * job[0].bucket
                     self.stats["bytes_h2d"] += nbytes
                     self.stats["shard_launches"][s] += 1
                     self.stats["shard_batches"][s] += len(parts)
@@ -1387,6 +1412,12 @@ class FeatureService:
                         self.stats["max_inflight"],
                         sum(len(i) for i in self._inflights))
                     self._busy[s] -= 1
+                    if launch is not OFF:
+                        launch.set_metadata(
+                            seq=self._seq, klass=job[0].klass,
+                            bucket=job[0].bucket, lanes_used=len(job),
+                            tickets=ids(ch.ticket for ch in job))
+                    launch.__exit__(None, None, None)
                     if self.rebalance_every and (
                             self.stats["launches"]
                             + self.stats["host_gathers"] - self._mon_mark
@@ -1394,7 +1425,8 @@ class FeatureService:
                         self._rebalance_locked()
             else:
                 try:
-                    arr, win_ex, dt, by_hedge = self._await_flight(s, fl)
+                    arr, win_ex, dt, by_hedge = self._await_flight(s, fl,
+                                                                   seq)
                 except Exception as e:
                     with self._lock:
                         self._handle_launch_failure(s, fl.group, fl.ex, e)
@@ -1402,6 +1434,7 @@ class FeatureService:
                         self._busy[s] -= 1
                         if self._all_idle():
                             self._idle.notify_all()
+                    retire.__exit__(None, None, None)
                     continue
                 with self._lock:
                     now = time.perf_counter()
@@ -1413,6 +1446,7 @@ class FeatureService:
                         self._strike_locked(fl.ex, s, now)
                     if self._retire(arr, fl.parts):
                         self._cv.notify_all()
+                    retire.__exit__(None, None, None)
                     self._pump_retiring = None
                     self._busy[s] -= 1
                     if self._all_idle():
@@ -1426,13 +1460,14 @@ class FeatureService:
         r = getattr(buf, "is_ready", None)
         return True if r is None else bool(r())
 
-    def _await_flight(self, s: int, fl: _Flight):
+    def _await_flight(self, s: int, fl: _Flight, seq: int):
         """Block (outside the lock) until one of the flight's buffers is
         ready; returns ``(host array, winning executor, round-trip
         seconds, won_by_hedge)``.
 
         Fast path — no injected stall and hedging not armed — is the
-        plain blocking ``np.asarray`` the pre-hedge pump did. Hedging
+        plain blocking ``np.asarray`` the pre-hedge pump did, in the
+        ``pump.fetch`` span of launch ``seq``. Hedging
         arms only when the policy allows it, the shard has more than one
         stream, and its straggler detector is past warmup (an untrained
         EWMA would hedge compile time); the cutoff is
@@ -1447,7 +1482,9 @@ class FeatureService:
                      and det.n > det.warmup
                      and self._sharded_ex.n_streams(s) > 1)
         if not can_hedge and fl.ready_at == 0.0:
-            arr = np.asarray(fl.dev)      # blocks on device, unlocked
+            with span("pump.fetch", seq=seq) as fetch:
+                arr = np.asarray(fl.dev)      # blocks on device, unlocked
+                fetch.set_metadata(nbytes=arr.nbytes)
             return arr, fl.ex, time.perf_counter() - fl.t0, False
         cutoff = det.hedge_cutoff(p.hedge_factor, p.hedge_min_s)
         while True:
@@ -1511,7 +1548,10 @@ class FeatureService:
         ``deadline_ms`` expired resolves it to :class:`DeadlineExceeded`
         and is dropped BEFORE launch, and the take stops at a selected-
         class chunk still in retry backoff (``not_before`` ahead of
-        ``now``) — so the group may come back empty."""
+        ``now``) — so the group may come back empty.
+
+        Each chunk taken records its queue wait, ``now`` minus its
+        enqueue time, in its class's ``queue`` histogram."""
         klass, _head, _hold = self._select_class(queue, now)
         if klass is None:
             return []
@@ -1546,6 +1586,9 @@ class FeatureService:
         rest.extend(queue)
         queue.clear()
         queue.extend(rest)
+        waits = self._class_stats[klass]["queue"]
+        for ch in group:
+            waits.record(now - ch.t_enq)
         return group
 
     def _launch(self, group: list[_Chunk], s: int, ex, stream: int):
@@ -1656,7 +1699,6 @@ class FeatureService:
             if t0 is not None:
                 lat = time.perf_counter() - t0
                 self.stats["latency_s_total"] += lat
-                self.latencies.append(lat)
                 self.stats["completed"] += 1
                 self.stats["latency_samples_total"] += 1
                 self._lat_hist.record(lat)
@@ -2410,11 +2452,9 @@ class FeatureService:
     def latency_percentile(self, q: float,
                            klass: str | None = None) -> float:
         """The q-th per-ticket latency percentile in SECONDS from the
-        streaming histogram — every completed ticket since construction,
-        not the ``latencies`` deque's most-recent-8192 window (which is
-        what ``np.percentile(svc.latencies, ...)`` silently reports once
-        ``stats['latency_samples_total']`` exceeds the window).
-        ``klass`` narrows to one request class."""
+        streaming histogram — every completed ticket since construction
+        or the last :meth:`reset_latency_window`. ``klass`` narrows to one
+        request class."""
         with self._lock:
             h = self._lat_hist if klass is None \
                 else self._class_stats[klass]["hist"]
@@ -2422,10 +2462,12 @@ class FeatureService:
 
     def class_stats(self) -> dict[str, dict]:
         """Per-request-class serving picture: counts (requests /
-        completed / failed / pending / rows) plus the class's streaming
+        completed / failed / pending / rows), the class's streaming
         latency summary (p50/p99/min/max/mean ms over ALL its completed
-        tickets). JSON-safe — what the front door's stats endpoint and
-        the per-class SLO gates read."""
+        tickets) and its queue wait (``queue_p50_ms``/``queue_p99_ms``:
+        enqueue to the pump's take, over every chunk taken). JSON-safe —
+        what the front door's stats endpoint and the per-class SLO gates
+        read."""
         with self._lock:
             out = {}
             for name, cs in self._class_stats.items():
@@ -2436,22 +2478,24 @@ class FeatureService:
                     "failed": cs["failed"],
                     "pending": max(cs["requests"] - resolved, 0),
                     "rows": cs["rows"],
-                    **cs["hist"].summary()}
+                    **cs["hist"].summary(),
+                    "queue_p50_ms": cs["queue"].percentile(50) * 1e3,
+                    "queue_p99_ms": cs["queue"].percentile(99) * 1e3}
             return out
 
     def reset_latency_window(self) -> None:
         """Start a fresh latency observation window: clears the
-        bench-compat ``latencies`` deque, the streaming histograms
-        (global and per class) and ``stats['latency_samples_total']``.
+        streaming histograms (global, per class and the per-class queue
+        waits) and ``stats['latency_samples_total']``.
         The serving ledger (requests/completed/failed counters) is NOT
         touched — this resets what the percentiles COVER (post-warmup
         benching, scrape intervals), not what happened."""
         with self._lock:
-            self.latencies.clear()
             self._lat_hist = LatencyHistogram()
             self.stats["latency_samples_total"] = 0
             for cs in self._class_stats.values():
                 cs["hist"] = LatencyHistogram()
+                cs["queue"] = LatencyHistogram()
 
     def throughput_stats(self, wall_s: float) -> dict:
         rows = self.stats["rows"]
