@@ -39,7 +39,8 @@ class RequestClass:
 
     ``coalesce``/``linger_us`` of ``None`` inherit the service-wide
     settings; a class's ``coalesce`` is additionally capped at the
-    service's (launch buffers are sized for the service-wide depth).
+    service's, and sizes the class's launches: a launch gathers
+    ``coalesce x bucket`` rows.
     ``aging_s`` is the anti-starvation rate: a queued chunk's effective
     priority is ``priority + waited_seconds / aging_s``, so every
     ``aging_s`` seconds of queue time is worth one priority level.

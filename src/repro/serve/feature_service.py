@@ -36,7 +36,8 @@ Packed serving ships indices only: every chunk — word-aligned range or
 arbitrary row set — is served by the indexed gather
 (:meth:`FeatureExecutor._rows_future`): the kernel computes word index +
 bit offset against the resident streams, so the per-launch host->device
-traffic is the padded (coalesce x bucket) int32 index vector.
+traffic is the padded (lanes x bucket) int32 index vector, where a
+launch's lanes are its request class's coalesce depth.
 ``stats['bytes_h2d']`` therefore reports INDEX bytes; int32 plans still
 ship (C, bucket) code slices and account those. Per-shard attribution
 lives in ``stats['shard_launches'] / ['shard_batches'] /
@@ -1057,12 +1058,20 @@ class FeatureService:
     def _coalesce_for(self, rc: RequestClass) -> int:
         """Effective coalescing depth for one class: the class's own when
         set, else the service-wide depth — capped at the service depth
-        either way (launch buffers are sized ``(coalesce, bucket)``) and
-        forced to 1 on unpacked plans (no coalesced launches there)."""
+        either way and forced to 1 on unpacked plans (no coalesced
+        launches there). A launch of the class gathers this many lanes
+        (:meth:`_lanes`)."""
         if not self.packed:
             return 1
         c = rc.coalesce if rc.coalesce is not None else self.coalesce
         return max(1, min(c, self.coalesce))
+
+    def _lanes(self, group: list[_Chunk]) -> int:
+        """Lanes one launch of ``group`` gathers: its class's coalesce
+        depth, so each (class depth, bucket) pair is one compiled shape
+        and a singleton class gathers and copies one lane, not the
+        service-wide depth."""
+        return self._coalesce_for(self._classes[group[0].klass])
 
     def _linger_for(self, rc: RequestClass) -> float:
         return rc.linger_us * 1e-6 if rc.linger_us is not None \
@@ -1402,8 +1411,8 @@ class FeatureService:
                     self._pump_taken = None
                     self.stats["launches"] += 1
                     self.stats["batches"] += len(parts)
-                    self.stats["launched_rows"] += \
-                        self.coalesce * job[0].bucket
+                    lanes = self._lanes(job)
+                    self.stats["launched_rows"] += lanes * job[0].bucket
                     self.stats["bytes_h2d"] += nbytes
                     self.stats["shard_launches"][s] += 1
                     self.stats["shard_batches"][s] += len(parts)
@@ -1416,6 +1425,7 @@ class FeatureService:
                         launch.set_metadata(
                             seq=self._seq, klass=job[0].klass,
                             bucket=job[0].bucket, lanes_used=len(job),
+                            lanes=lanes,
                             tickets=ids(ch.ticket for ch in job))
                     launch.__exit__(None, None, None)
                     if self.rebalance_every and (
@@ -1595,10 +1605,12 @@ class FeatureService:
         """Dispatch ONE launch for a coalesced group on ``ex`` — the
         shard-``s`` stream :meth:`_pick_stream` chose (pump thread only).
 
-        Packed plans: a flat (coalesce * bucket,) int32 SHARD-LOCAL index
-        vector — padded to the full coalesce width so every launch shares
-        one compiled shape per bucket — into the shard executor's indexed
-        gather; host->device traffic is the indices alone. int32 plans:
+        Packed plans: a flat (lanes * bucket,) int32 SHARD-LOCAL index
+        vector — padded to the group's class width (:meth:`_lanes`) so
+        every launch of a class shares one compiled shape per bucket —
+        into the shard executor's indexed gather; host->device traffic is
+        the indices alone. A hedged duplicate of the same group gets the
+        same width, so its parts' row offsets hold. int32 plans:
         the classic stacked code slice for a single chunk. Either way the
         launch buffer is a flat (rows, F) array and each part records its
         chunk's row offset into it.
@@ -1617,7 +1629,7 @@ class FeatureService:
                                                klass=group[0].klass)
         bucket = group[0].bucket
         if self.packed:
-            mat = np.empty((self.coalesce, bucket), np.int32)
+            mat = np.empty((self._lanes(group), bucket), np.int32)
             for i, ch in enumerate(group):
                 mat[i] = pad_rows_edge(ch.rows, bucket)
             mat[len(group):] = mat[len(group) - 1]   # surplus lanes unread

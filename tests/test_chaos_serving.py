@@ -24,7 +24,8 @@ import pytest
 from repro.columnar import Table
 from repro.core import (FeatureSet, FeaturePlan, FeatureExecutor)
 from repro.serve import (DeadlineExceeded, FaultInjector, FaultPolicy,
-                         FeatureService, InjectedFault, ServeError)
+                         FeatureService, InjectedFault, ServeError,
+                         default_classes)
 from repro.serve.faults import StreamBreaker
 
 
@@ -551,6 +552,39 @@ def test_hedged_launch_beats_stalled_primary():
         assert st["hedge_wins"] >= 1
         assert dt < 0.5                            # did not ride the stall
         assert st["completed"] == completed0 + 1   # no double-count
+        assert st["failed_tickets"] == 0
+
+
+def test_hedged_one_lane_interactive_launch_retires_bit_exact():
+    """The stalled-primary setup with an ``interactive`` submit on a
+    4-deep service: the class launches one lane, not four, and its hedged
+    duplicate is launched at the same width, so the parts' row offsets
+    hold and the duplicate's buffer resolves the ticket bit-exact."""
+    t, fs = _mixed_table()
+    inj = FaultInjector()
+    pol = FaultPolicy(hedge=True, hedge_min_s=0.02, hedge_factor=2.0,
+                      straggler_min_s=10.0, breaker_fails=100)
+    with FeatureService(FeaturePlan(t, fs, packed=True), sharded=True,
+                        buckets=(64,), coalesce=4, faults=inj,
+                        fault_policy=pol,
+                        classes=default_classes()) as svc:
+        svc.add_replica(0)
+        rows = np.arange(5, 52)
+        for _ in range(10):                        # warm EWMA past warmup
+            svc.result(svc.submit(rows, klass="interactive"), timeout=60)
+        st0 = dict(svc.stats)
+        assert st0["launched_rows"] == 64 * st0["launches"]   # one lane
+        inj.stall_launches(0.6, 1, shard=0)        # next primary launch
+        t0 = time.perf_counter()
+        out = svc.result(svc.submit(rows, klass="interactive"), timeout=60)
+        dt = time.perf_counter() - t0
+        np.testing.assert_array_equal(out, _reference(t, fs, [rows])[0])
+        st = dict(svc.stats)
+        assert st["hedges"] >= 1
+        assert st["hedge_wins"] >= 1
+        assert dt < 0.5                            # did not ride the stall
+        assert st["completed"] == st0["completed"] + 1
+        assert st["launched_rows"] - st0["launched_rows"] == 64
         assert st["failed_tickets"] == 0
 
 

@@ -22,6 +22,7 @@ only on the scheduler's class selection, not on submission timing. The
 randomized sweep reads ``FRONTEND_SWEEP_SEEDS`` (nightly raises it).
 """
 import asyncio
+import dataclasses
 import json
 import os
 import time
@@ -372,6 +373,54 @@ def test_admission_releases_on_serve_error():
         assert st["classes"]["a"]["outstanding"] == 0
         assert st["classes"]["a"]["failed"] == 1
         assert st["availability_admitted"] == 0.0
+
+
+def test_class_width_launches_refuse_and_fail_what_padded_ones_do():
+    """The burst a host freeze leaves at the front door — ``max_inflight
+    + queue_depth + 50`` requests at once, pump paused — against the
+    preset ``interactive`` class (1-lane launches) and the same class at
+    the service's 4-lane width (coalesce 4, no linger). Launch width
+    changes no failure semantics: both refuse exactly the 50 past the
+    bound with Overloaded, fail none with ServeError, and serve the rest
+    bit-exact."""
+    t, fs = _mixed_table()
+    rng = np.random.default_rng(23)
+    slim = default_classes()[0]
+    padded = dataclasses.replace(slim, coalesce=4, linger_us=0.0)
+    bound = slim.max_inflight + slim.queue_depth
+    reqs = [rng.integers(0, 3000, int(rng.integers(8, 65)))
+            for _ in range(bound + 50)]
+    want = _reference(t, fs, reqs[:bound])
+    launches = {}
+    for rc in (slim, padded):
+        with FeatureService(FeaturePlan(t, fs, packed=True), buckets=(64,),
+                            coalesce=4, classes=(rc,)) as svc:
+            fe = FeatureFrontend(svc)
+            fe.result(fe.submit(reqs[0]), timeout=60)   # compile first
+            before = dict(svc.stats)
+            svc.pause()
+            tickets, refused = [], 0
+            for r in reqs:
+                try:
+                    tickets.append(fe.submit(r))
+                except Overloaded:
+                    refused += 1
+            svc.resume()
+            got, failed = [], 0
+            for tk in tickets:
+                try:
+                    got.append(fe.result(tk, timeout=60))
+                except ServeError:
+                    failed += 1
+            st = dict(svc.stats)
+        assert (refused, failed, len(got)) == (50, 0, bound)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, w)
+        n = st["launches"] - before["launches"]
+        assert st["launched_rows"] - before["launched_rows"] == \
+            n * svc._coalesce_for(rc) * 64
+        launches[rc.coalesce] = n
+    assert launches == {1: bound, 4: bound // 4}
 
 
 # -- per-class deadlines -------------------------------------------------------------

@@ -22,7 +22,7 @@ from repro.kernels.adv_gather.ref import (adv_gather_multi_ref,
                                           adv_gather_packed_ref,
                                           adv_gather_packed_rows_ref)
 from repro.kernels.bitunpack.kernel import tpu_width
-from repro.serve import FeatureService
+from repro.serve import FeatureService, default_classes
 
 # satellite requirement: every storage width class, incl. non-divisors
 # (3 -> 4, 6 -> 8, 12 -> 16) that force a device-width repack
@@ -404,6 +404,40 @@ def test_packed_service_coalesces_launches():
         np.testing.assert_array_equal(out[tk],
                                       np.asarray(pipe.batch(
                                           np.arange(s, s + 128))))
+
+
+@pytest.mark.parametrize("bucket", [64, 256])
+@pytest.mark.parametrize("klass,lanes", [
+    ("interactive", 1), ("batch", 4), ("background", 4)])
+def test_packed_launch_width_is_the_class_coalesce_depth(klass, lanes,
+                                                         bucket):
+    """A launch gathers its class's coalesce depth of lanes: one
+    ``bucket`` lane for ``interactive`` (coalesce 1), the service's 4 for
+    classes without their own depth — surplus lanes of a partial group
+    included. ``stats['launched_rows']`` counts exactly those lanes."""
+    rng = np.random.default_rng(17)
+    n = 4096
+    t = Table.from_data({
+        "age": rng.integers(18, 80, n),
+        "state": np.array(["CA", "OR", "WA", "NY"])[rng.integers(0, 4, n)],
+    })
+    fs = FeatureSet().add("age", "zscore").add("state", "onehot")
+    pipe = FeaturePipeline(t, fs)
+    reqs = [rng.integers(0, n, bucket - 9 * i) for i in range(5)]
+    with FeatureService(FeaturePlan(t, fs, packed=True), buckets=(64, 256),
+                        coalesce=4, classes=default_classes()) as svc:
+        assert svc.coalesce == 4
+        svc.pause()                 # the whole burst queues: full groups
+        tickets = [svc.submit(r, klass=klass) for r in reqs]
+        svc.resume()
+        out = svc.drain()
+        st = dict(svc.stats)
+    launches = -(-len(reqs) // lanes)
+    assert st["launches"] == launches
+    assert st["launched_rows"] == launches * lanes * bucket
+    assert st["rows"] == sum(r.size for r in reqs)
+    for r, tk in zip(reqs, tickets):
+        np.testing.assert_array_equal(out[tk], np.asarray(pipe.batch(r)))
 
 
 def test_packed_service_poll_flushes_partial_group():

@@ -133,14 +133,17 @@ def test_a_ticket_is_followed_from_submit_to_launch_and_retire(traced):
     assert {m["seq"] for *_, m in ev["pump.fetch"]} == launch_seq
     for *_, m in ev["pump.launch"]:
         assert m["klass"] == "interactive" and m["lanes_used"] == 1
+        # the class's depth (1), not the service's
+        assert m["lanes"] == 1 < coalesce
         assert m["bucket"] in (64, 256) and m["shard"] == 0
 
 
 def test_launched_rows_counts_every_lane_of_the_traced_launches(traced):
     ev, _, before, after, coalesce = traced
     launched = after["launched_rows"] - before["launched_rows"]
-    assert launched == sum(coalesce * m["bucket"]
+    assert launched == sum(m["lanes"] * m["bucket"]
                            for *_, m in ev["pump.launch"])
+    assert launched == 2 * 64 + 2 * 256 < coalesce * (2 * 64 + 2 * 256)
     assert after["rows"] - before["rows"] == 480 < launched
 
 
@@ -181,7 +184,10 @@ def test_launched_rows_is_coalesce_times_bucket_per_launch():
                       timeout=60)
         st = svc.throughput_stats(1.0)
     assert st["launches"] == 12
-    assert st["launched_rows"] == svc.coalesce * 64 * st["launches"]
+    # interactive's coalesce depth is 1: one 64-row lane a launch
+    rc = svc.classes["interactive"]
+    assert rc.coalesce == 1 < svc.coalesce
+    assert st["launched_rows"] == rc.coalesce * 64 * st["launches"]
     assert st["rows"] == 600
 
 
