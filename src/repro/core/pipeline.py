@@ -87,6 +87,12 @@ from repro.kernels.predicate_scan import ops as scan_ops
 from repro.spans import span
 
 
+# matches one lane of a filtered ("where") launch compacts and gathers: the
+# launch shape of a where request is (lanes, WHERE_LANE) whatever its match
+# count, so one compiled program serves every predicate of a structure
+WHERE_LANE = 65536
+
+
 def _pad32(n: int) -> int:
     """Round up to the word-alignment quantum: a row index that is a
     multiple of 32 is word-aligned at EVERY divisor width (32/db | 32)."""
@@ -206,16 +212,14 @@ def _packed_fused_rows(flat_words, table, row_offsets, card_limits, rows, *,
                                           out_dim, bn=bn, bk=bk)
 
 
-@functools.partial(jax.jit, static_argnames=("dbs", "word_offs", "cap"))
-def _packed_split_where(flat_words, tables, mask, *, dbs, word_offs, cap):
-    """Selection-mask -> (rows, features) in ONE launch: the bitmap
-    compaction and the indexed gather fuse into a single jit, so the
-    compacted index vector never surfaces as a separate dispatch on the
-    filtered-serving hot path (each dependent eager step costs a dispatch
-    + device round trip)."""
-    rows = scan_ops.compact_rows(mask, cap)
-    return rows, adv_ops.adv_gather_packed_rows_split(flat_words, word_offs,
-                                                      dbs, tables, rows)
+@functools.partial(jax.jit, static_argnames=("lane",))
+def _where_compact(ranks, offs, *, lane):
+    """Lanes of matches -> one (lanes * lane,) row vector in ONE launch:
+    lane i holds the matches [offs[i], offs[i] + lane) of the running match
+    count ``ranks[i]``, so the shape is the same whatever the match count
+    (a lane past the last match holds row 0; callers slice it off)."""
+    return jnp.concatenate([scan_ops.compact_block(r, offs[i], lane)
+                            for i, r in enumerate(ranks)])
 
 
 class _ShardStats(dict):
@@ -1232,6 +1236,21 @@ class FeatureExecutor:
             tuple(self.plan.device_bits), terms, self.plan.n_rows,
             combine, packed=packed)
 
+    def _ranks_count_future(self, pred):
+        """(ranks, count) device futures: the selection mask's running
+        match count and its last entry, from one scan launch (split path;
+        the Pallas path adds an eager cumsum)."""
+        terms, combine, packed = self._compiled_pred(pred)
+        if self.use_kernel:
+            ranks = jnp.cumsum(self._mask_future(terms, combine)
+                               .astype(jnp.int32))
+            return ranks, ranks[-1]
+        self.ensure_range_capacity(self.plan.n_rows)
+        return scan_ops.predicate_scan_split_ranks(
+            self._flat_words, self._word_offs,
+            tuple(self.plan.device_bits), terms, self.plan.n_rows,
+            combine, packed=packed)
+
     def predicate_mask(self, pred) -> jnp.ndarray:
         """(n_rows,) bool device mask for a value-space predicate."""
         terms, combine, packed = self._compiled_pred(pred)
@@ -1241,36 +1260,67 @@ class FeatureExecutor:
         """SELECT COUNT(*) WHERE pred — one device scan + reduction."""
         return int(self._mask_count_future(pred)[1])
 
+    def where_ranks(self, pred) -> tuple[jnp.ndarray, int]:
+        """(device running match count, match count) of ``pred``: one
+        scan launch and one scalar sync. The ranks stay on the device and
+        feed :meth:`where_lanes`, block by block."""
+        ranks, cnt = self._ranks_count_future(pred)
+        return ranks, int(cnt)
+
+    @property
+    def where_lane(self) -> int:
+        """Matches one lane of a where launch covers: ``WHERE_LANE``, or
+        the whole padded table when it is shorter."""
+        return min(WHERE_LANE, _pad32(self.plan.n_rows))
+
+    def where_lanes(self, ranks: tuple, offs) -> tuple[jnp.ndarray,
+                                                       jnp.ndarray]:
+        """One compaction launch and one gather launch of ``len(ranks)``
+        lanes: lane i is the matches [offs[i], offs[i] + where_lane) of
+        running match count ``ranks[i]``. Returns device (rows, features)
+        of (lanes * where_lane) rows — one compiled shape per lane count,
+        whatever the offsets and counts; the row indices go from the
+        compaction to the gather without visiting the host."""
+        rows = _where_compact(tuple(ranks), np.asarray(offs, np.int32),
+                              lane=self.where_lane)
+        return rows, self._rows_future(rows)
+
+    def _where_rows(self, ranks: jnp.ndarray, cnt: int) -> np.ndarray:
+        """The ``cnt`` matching rows of ``ranks`` on the host, compacted
+        on device one ``where_lane`` block at a time."""
+        lane = self.where_lane
+        blocks = [scan_ops.compact_block(ranks, off, lane)
+                  for off in range(0, cnt, lane)]
+        return np.concatenate([np.asarray(b) for b in blocks])[:cnt] \
+            .astype(np.int64)
+
     def filtered_rows(self, pred) -> np.ndarray:
         """Matching row indices (ascending int64), compacted on device."""
-        mask, cnt_dev = self._mask_count_future(pred)
-        cnt = int(cnt_dev)             # one scalar sync: the static shape
+        ranks, cnt = self.where_ranks(pred)
         if cnt == 0:
             return np.zeros(0, np.int64)
-        rows = scan_ops.compact_rows(mask, _pad32(cnt))
-        return np.asarray(rows[:cnt]).astype(np.int64)
+        return self._where_rows(ranks, cnt)
+
+    def _where_blocks(self, ranks: jnp.ndarray, cnt: int) -> list:
+        """Dispatch one 1-lane :meth:`where_lanes` launch per block of
+        ``cnt`` matches; their (rows, features) device futures."""
+        return [self.where_lanes((ranks,), [off])
+                for off in range(0, cnt, self.where_lane)]
 
     def batch_where(self, pred) -> tuple[np.ndarray, jnp.ndarray]:
         """Filtered featurization: scan -> compact -> indexed gather, all
         against the resident streams. Returns (rows, features) for the
-        matching rows in ascending row order. The ONE host sync is the
-        match count (the static launch shape); the compacted index vector
-        feeds the gather without ever visiting the host."""
-        mask, cnt_dev = self._mask_count_future(pred)
-        cnt = int(cnt_dev)
+        matching rows in ascending row order. The ONE host sync before the
+        gathers is the match count; each block of matches compacts and
+        gathers in one fixed-shape launch, so no count compiles anew."""
+        ranks, cnt = self.where_ranks(pred)
         if cnt == 0:
             return (np.zeros(0, np.int64),
                     jnp.zeros((0, self.plan.out_dim), jnp.float32))
-        if self.kernel_active:
-            rows_dev = scan_ops.compact_rows(mask, _pad32(cnt))
-            feats = self._rows_future(rows_dev)    # device-to-device indices
-            return np.asarray(rows_dev[:cnt]).astype(np.int64), feats[:cnt]
-        self.ensure_range_capacity(self.plan.n_rows)
-        rows_dev, feats = _packed_split_where(
-            self._flat_words, self._device_tables(), mask,
-            dbs=tuple(self.plan.device_bits), word_offs=self._word_offs,
-            cap=_pad32(cnt))
-        return np.asarray(rows_dev[:cnt]).astype(np.int64), feats[:cnt]
+        blocks = self._where_blocks(ranks, cnt)
+        rows = np.concatenate([np.asarray(r) for r, _ in blocks])[:cnt]
+        feats = jnp.concatenate([f for _, f in blocks])[:cnt]
+        return rows.astype(np.int64), feats
 
     def _masked_counts_from(self, column: str, mask: jnp.ndarray) -> jnp.ndarray:
         """Async (K,) per-code counts of ``column`` under a device mask."""
@@ -1734,44 +1784,46 @@ class ShardedFeatureExecutor:
     def count_where(self, pred) -> int:
         return sum(int(m.sum()) for _, _, m in self._shard_masks(pred))
 
+    def _shard_ranks(self, pred) -> list[tuple[int, FeatureExecutor,
+                                               jnp.ndarray, int]]:
+        """(shard, executor, running match count, count) of every shard
+        with a match: every shard's scan is dispatched before any count is
+        read, each on the executor that owns (or replicates) its stream."""
+        futs = []
+        for s in range(self.n_shards):
+            ex = self.next_executor(s)
+            futs.append((s, ex, *ex._ranks_count_future(pred)))
+        return [(s, ex, ranks, int(cnt)) for s, ex, ranks, cnt in futs
+                if int(cnt)]
+
     def filtered_rows(self, pred) -> np.ndarray:
         """Matching GLOBAL row indices, ascending (shards are ordered by
         start row, so shard-order concatenation IS global row order)."""
         starts, _ = self._routing
-        parts = []
-        for s, ex, mask in self._shard_masks(pred):
-            cnt = int(mask.sum())
-            if cnt == 0:
-                continue
-            rows = scan_ops.compact_rows(mask, _pad32(cnt))
-            parts.append(np.asarray(rows[:cnt]).astype(np.int64)
-                         + int(starts[s]))
+        parts = [ex._where_rows(ranks, cnt) + int(starts[s])
+                 for s, ex, ranks, cnt in self._shard_ranks(pred)]
         return np.concatenate(parts) if parts else np.zeros(0, np.int64)
 
     def batch_where(self, pred) -> tuple[np.ndarray, jnp.ndarray]:
         """Filtered featurization across the mesh: each shard scans its own
-        resident stream, compacts its matches on device, and gathers them
-        LOCALLY — no shard ships bytes to another device; the host only
-        assembles the per-shard results in global row order."""
+        resident stream, then compacts and gathers its matches LOCALLY in
+        fixed-shape blocks — no shard ships bytes to another device; the
+        host only assembles the per-shard results in global row order."""
         starts, _ = self._routing
-        futs, total = [], 0
-        for s, ex, mask in self._shard_masks(pred):
-            cnt = int(mask.sum())
-            if cnt == 0:
-                continue
-            rows = scan_ops.compact_rows(mask, _pad32(cnt))
-            futs.append((s, ex._rows_future(rows), rows, cnt))
-            total += cnt
+        futs = [(s, cnt, ex._where_blocks(ranks, cnt))
+                for s, ex, ranks, cnt in self._shard_ranks(pred)]
         if not futs:
             return (np.zeros(0, np.int64),
                     jnp.zeros((0, self.plan.out_dim), jnp.float32))
+        total = sum(cnt for _, cnt, _ in futs)
         rows_out = np.empty(total, np.int64)
         feats_out = np.empty((total, self.plan.out_dim), np.float32)
         off = 0
-        for s, fut, rows, cnt in futs:     # all dispatched; block in order
-            rows_out[off:off + cnt] = \
-                np.asarray(rows[:cnt]).astype(np.int64) + int(starts[s])
-            feats_out[off:off + cnt] = np.asarray(fut)[:cnt]
+        for s, cnt, blocks in futs:    # all dispatched; block in order
+            rows = np.concatenate([np.asarray(r) for r, _ in blocks])
+            feats = np.concatenate([np.asarray(f) for _, f in blocks])
+            rows_out[off:off + cnt] = rows[:cnt] + int(starts[s])
+            feats_out[off:off + cnt] = feats[:cnt]
             off += cnt
         return rows_out, jnp.asarray(feats_out)
 

@@ -18,7 +18,10 @@ Downstream pieces of the pushdown pipeline:
 - :func:`compact_rows` — device-side bitmap -> row-index compaction with a
   static output shape (the pad-to-static-bucket contract), feeding
   ``adv_gather_packed_rows`` so "scan -> compact -> gather" never leaves
-  the device.
+  the device. :func:`compact_block` is its windowed form over the mask's
+  running match count (:func:`predicate_scan_split_ranks`): a fixed-size
+  block of matches at a traced offset, so one compiled shape serves every
+  match count.
 - :func:`masked_counts` — masked per-code histogram over a column's
   resident words (the ``kernels/hist`` machinery with a mask lane):
   count/sum/mean of the column under a predicate then follow from K
@@ -183,6 +186,19 @@ def _scan_split_count(flat_words, bounds, flat_lut, *, word_offs, dbs, n,
     return mask, jnp.sum(mask.astype(jnp.int32))
 
 
+@functools.partial(jax.jit, static_argnames=_SCAN_STATICS)
+def _scan_split_ranks(flat_words, bounds, flat_lut, *, word_offs, dbs, n,
+                      cols, kinds, lut_offs, lut_lens, combine):
+    """Scan + running match count in ONE launch: ``ranks[i]`` is the number
+    of matches in rows [0, i], so ``ranks[-1]`` is the count and
+    :func:`compact_block` finds any block of matches without a rescan."""
+    mask = _scan_body(flat_words, bounds, flat_lut, word_offs=word_offs,
+                      dbs=dbs, n=n, cols=cols, kinds=kinds,
+                      lut_offs=lut_offs, lut_lens=lut_lens, combine=combine)
+    ranks = jnp.cumsum(mask.astype(jnp.int32))
+    return ranks, ranks[-1]
+
+
 def pack_terms(terms, dbs):
     """Pre-pack a term list for repeated scans: (bounds, flat_lut, statics)
     with the data halves already on device. A deployed filter family scans
@@ -220,6 +236,38 @@ def predicate_scan_split_count(flat_words: jnp.ndarray, word_offs, dbs,
                              lut_lens=lut_lens, combine=combine)
 
 
+def predicate_scan_split_ranks(flat_words: jnp.ndarray, word_offs, dbs,
+                               terms, n: int, combine: str = "and",
+                               packed=None):
+    """Split scan variant returning (ranks, match-count) from one launch:
+    the (n,) int32 running match count and its last entry."""
+    if combine not in ("and", "or"):
+        raise ValueError(f"unknown combinator {combine!r}")
+    bounds, flat_lut, (cols, kinds, lut_offs, lut_lens) = \
+        packed if packed is not None else _pack_terms(terms, dbs)
+    return _scan_split_ranks(flat_words, bounds, flat_lut,
+                             word_offs=tuple(word_offs), dbs=tuple(dbs),
+                             n=n, cols=cols, kinds=kinds, lut_offs=lut_offs,
+                             lut_lens=lut_lens, combine=combine)
+
+
+@functools.partial(jax.jit, static_argnames=("cap", "fill"))
+def compact_block(ranks: jnp.ndarray, off, cap: int,
+                  fill: int = 0) -> jnp.ndarray:
+    """Matches [off, off + cap) as a static-shape (cap,) int32 row vector.
+
+    ``ranks`` is a mask's running match count (inclusive cumsum): entry j
+    is the row of match ``off + j`` (0-based), the first row whose running
+    count reaches ``off + j + 1``. Entries past the last match hold
+    ``fill``, a valid row index, so the block feeds the indexed gather
+    directly and callers slice the valid prefix off the output. ``off`` is
+    traced: one compiled shape serves every offset and every count.
+    """
+    q = off + jnp.arange(1, cap + 1, dtype=jnp.int32)
+    rows = jnp.searchsorted(ranks, q, side="left")
+    return jnp.where(rows < ranks.shape[0], rows, fill).astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("cap", "fill"))
 def compact_rows(mask: jnp.ndarray, cap: int, fill: int = 0) -> jnp.ndarray:
     """Device-side bitmap compaction: ascending matching row indices as a
@@ -235,10 +283,7 @@ def compact_rows(mask: jnp.ndarray, cap: int, fill: int = 0) -> jnp.ndarray:
     all-gather form avoids XLA:CPU's element-at-a-time scatter lowering,
     which costs ~8x more at serving shapes.
     """
-    c = jnp.cumsum(mask.astype(jnp.int32))
-    rows = jnp.searchsorted(c, jnp.arange(1, cap + 1, dtype=jnp.int32),
-                            side="left")
-    return jnp.where(rows < mask.shape[0], rows, fill).astype(jnp.int32)
+    return compact_block(jnp.cumsum(mask.astype(jnp.int32)), 0, cap, fill)
 
 
 @functools.partial(jax.jit, static_argnames=("off", "db", "n", "k"))

@@ -37,7 +37,10 @@ arbitrary row set — is served by the indexed gather
 (:meth:`FeatureExecutor._rows_future`): the kernel computes word index +
 bit offset against the resident streams, so the per-launch host->device
 traffic is the padded (lanes x bucket) int32 index vector, where a
-launch's lanes are its request class's coalesce depth.
+launch's lanes are its request class's coalesce depth. A one-shard
+service's ``where`` requests ship less still: each lane is a block of
+matches that the device compacts from the scan's running match count, so
+only the lanes' match offsets cross.
 ``stats['bytes_h2d']`` therefore reports INDEX bytes; int32 plans still
 ship (C, bucket) code slices and account those. Per-shard attribution
 lives in ``stats['shard_launches'] / ['shard_batches'] /
@@ -223,6 +226,10 @@ class _Chunk:
     not_before: float = 0.0         # retry backoff deadline (perf_counter)
     avoid: frozenset = frozenset()  # stream tokens this chunk failed on
     klass: str = "default"          # request class (pump scheduling key)
+    # a where request's device running match count: the chunk is then the
+    # matches [dest, dest + n), compacted on the device at launch, and
+    # ``rows`` is None
+    ranks: object = field(default=None, compare=False)
 
 
 @dataclass
@@ -427,7 +434,7 @@ class FeatureService:
                       "latency_s_total": 0.0, "completed": 0,
                       "latency_samples_total": 0,
                       "packed_ranges": 0, "bytes_h2d": 0, "split_requests": 0,
-                      "filtered_requests": 0,
+                      "filtered_requests": 0, "filtered_rows": 0,
                       "retries": 0, "failovers": 0, "timeouts": 0,
                       "failed_tickets": 0, "unhealthy_shards": 0,
                       "stragglers": 0,
@@ -866,6 +873,11 @@ class FeatureService:
         predicate scan over the resident word streams (per shard on a mesh
         service) and then pumped through the SAME coalescing launch path as
         any explicit request — "serve features WHERE ..." as one ticket.
+        On a one-shard service the matches never visit the host: the scan
+        leaves their running count on the device and each launch compacts
+        and gathers the next fixed-size blocks of matches
+        (:meth:`FeatureExecutor.where_lanes`), so the launch shape is the
+        same whatever the match count.
 
         ``deadline_ms`` bounds the request's time in the system: chunks
         still QUEUED once it expires are dropped before launch and the
@@ -893,16 +905,24 @@ class FeatureService:
         if deadline_ms is not None and deadline_ms <= 0:
             raise ValueError("deadline_ms must be > 0")
         filtered = where is not None
+        ranks = None
         if filtered:
             if rows is not None:
                 raise ValueError("pass rows OR where, not both")
             if not self.packed:
                 raise RuntimeError("predicate-filtered serving needs a "
                                    "packed plan (resident word streams)")
-            ex = self._sharded_ex if self._sharded_ex is not None \
-                else self._executor
-            rows = ex.filtered_rows(where)
-            if rows.size == 0:
+            with span("serve.where") as sw:
+                if self._sharded_ex is None:
+                    ranks, count = self._executor.where_ranks(where)
+                    lane = self._executor.where_lane
+                else:
+                    rows = self._sharded_ex.filtered_rows(where)
+                    count, lane = rows.size, self.buckets[-1]
+                chunks = -(-count // lane)
+                sw.set_metadata(rows=count, launches=-(
+                    -chunks // self._coalesce_for(rc)))
+            if count == 0:
                 # empty selection: nothing to pump — mint a ticket whose
                 # (0, F) result is already on host (poll/result check the
                 # results map before the chunk ledger, so this short-
@@ -926,12 +946,15 @@ class FeatureService:
                 return ticket
         elif rows is None:
             raise ValueError("need rows or where")
-        rows = np.asarray(rows, dtype=np.int64).reshape(-1)
-        if rows.size == 0:
-            raise ValueError("empty request")
-        lo, hi = int(rows.min()), int(rows.max())
-        if lo < 0 or hi >= self.plan.n_rows:
-            raise IndexError(f"row indices out of range [0, {self.plan.n_rows})")
+        if ranks is None:
+            rows = np.asarray(rows, dtype=np.int64).reshape(-1)
+            if rows.size == 0:
+                raise ValueError("empty request")
+            lo, hi = int(rows.min()), int(rows.max())
+            if lo < 0 or hi >= self.plan.n_rows:
+                raise IndexError(
+                    f"row indices out of range [0, {self.plan.n_rows})")
+            count = rows.size
         # routing, chunking and the O(chunk) alignment scan are pure
         # functions of the request — do them OUTSIDE the lock. A pump-side
         # rebalance may swap the routing table between that work and the
@@ -941,18 +964,29 @@ class FeatureService:
         cap = self.buckets[-1]
         while True:
             gen = self._route_gen
-            pieces, padded, aligned = [], 0, 0
-            routed = self._route(rows, lo, hi)
-            for shard, local, dest in routed:
-                for start in range(0, local.shape[0], cap):
-                    chunk = local[start:start + cap]
-                    bucket = self._bucket(chunk.shape[0])
-                    padded += bucket - chunk.shape[0]
-                    if self.packed and self._aligned_range(chunk):
-                        aligned += 1
-                    d = start if dest is None else dest[start:start + cap]
-                    pieces.append(_Chunk(0, chunk, chunk.shape[0], bucket,
-                                         shard, d))
+            pieces, padded, aligned, split = [], 0, 0, False
+            if ranks is not None:
+                # one chunk per lane of matches; the pump coalesces them
+                # into the class's (lanes, where_lane) launch shape
+                for start in range(0, count, lane):
+                    n = min(lane, count - start)
+                    padded += lane - n
+                    pieces.append(_Chunk(0, None, n, lane, 0, start,
+                                         ranks=ranks))
+            else:
+                routed = self._route(rows, lo, hi)
+                split = len(routed) > 1
+                for shard, local, dest in routed:
+                    for start in range(0, local.shape[0], cap):
+                        chunk = local[start:start + cap]
+                        bucket = self._bucket(chunk.shape[0])
+                        padded += bucket - chunk.shape[0]
+                        if self.packed and self._aligned_range(chunk):
+                            aligned += 1
+                        d = start if dest is None \
+                            else dest[start:start + cap]
+                        pieces.append(_Chunk(0, chunk, chunk.shape[0],
+                                             bucket, shard, d))
             with self._lock:
                 self._check_pump()
                 if self._shutdown:
@@ -966,19 +1000,20 @@ class FeatureService:
                 if deadline_ms is not None:
                     self._deadlines[ticket] = now + deadline_ms / 1e3
                 self.stats["requests"] += 1
-                self.stats["rows"] += rows.size
+                self.stats["rows"] += count
                 self.stats["padded_rows"] += padded
                 self.stats["packed_ranges"] += aligned
                 if filtered:
                     self.stats["filtered_requests"] += 1
-                if len(routed) > 1:
+                    self.stats["filtered_rows"] += count
+                if split:
                     self.stats["split_requests"] += 1
                 self._chunks_total[ticket] = len(pieces)
-                self._ticket_rows[ticket] = rows.size
+                self._ticket_rows[ticket] = count
                 self._ticket_class[ticket] = klass
                 cs = self._class_stats[klass]
                 cs["requests"] += 1
-                cs["rows"] += rows.size
+                cs["rows"] += count
                 before = {}
                 for ch in pieces:
                     ch.ticket = ticket
@@ -1003,7 +1038,7 @@ class FeatureService:
                     if n0 == 0 or preempt or (n0 < self.coalesce <= n1):
                         self._work.notify_all()
                         break
-            sp.set_metadata(ticket=ticket, rows=rows.size)
+            sp.set_metadata(ticket=ticket, rows=count)
             return ticket
 
     # -- bucketing ------------------------------------------------------------------
@@ -1591,8 +1626,9 @@ class FeatureService:
                 break
             queue.popleft()
             if bucket is None:
-                bucket = ch.bucket
-            (group if ch.bucket == bucket else rest).append(ch)
+                bucket, where = ch.bucket, ch.ranks is not None
+            same = ch.bucket == bucket and (ch.ranks is not None) == where
+            (group if same else rest).append(ch)
         rest.extend(queue)
         queue.clear()
         queue.extend(rest)
@@ -1610,7 +1646,10 @@ class FeatureService:
         every launch of a class shares one compiled shape per bucket —
         into the shard executor's indexed gather; host->device traffic is
         the indices alone. A hedged duplicate of the same group gets the
-        same width, so its parts' row offsets hold. int32 plans:
+        same width, so its parts' row offsets hold. A group of where
+        lanes instead compacts each chunk's block of matches on the device
+        (:meth:`FeatureExecutor.where_lanes`) and ships only the lanes'
+        match offsets. int32 plans:
         the classic stacked code slice for a single chunk. Either way the
         launch buffer is a flat (rows, F) array and each part records its
         chunk's row offset into it.
@@ -1628,14 +1667,22 @@ class FeatureService:
                                                device=ex.device,
                                                klass=group[0].klass)
         bucket = group[0].bucket
+        parts = [(ch.ticket, ch.n, ch.dest, i * bucket)
+                 for i, ch in enumerate(group)]
+        if group[0].ranks is not None:
+            # where lanes: each chunk's block of matches, compacted and
+            # gathered on the device; surplus lanes repeat the last
+            lanes = [(ch.ranks, ch.dest) for ch in group]
+            lanes += lanes[-1:] * (self._lanes(group) - len(group))
+            _rows, dev = ex.where_lanes(tuple(r for r, _ in lanes),
+                                        [o for _, o in lanes])
+            return dev, parts, 4 * len(lanes), stall
         if self.packed:
             mat = np.empty((self._lanes(group), bucket), np.int32)
             for i, ch in enumerate(group):
                 mat[i] = pad_rows_edge(ch.rows, bucket)
             mat[len(group):] = mat[len(group) - 1]   # surplus lanes unread
             dev = ex._rows_future(mat.reshape(-1))
-            parts = [(ch.ticket, ch.n, ch.dest, i * bucket)
-                     for i, ch in enumerate(group)]
             return dev, parts, mat.nbytes, stall
         ch = group[0]
         codes = self._slice_padded(ch.rows, bucket)

@@ -260,3 +260,163 @@ def test_service_filtered_interleaves_with_plain_requests():
                     out, np.asarray(ref_ex.batch(rows)), rtol=1e-6)
             else:
                 np.testing.assert_allclose(out, where_ref, rtol=1e-6)
+
+
+# -- where lanes on TPC-H lineitem: the served path against the plain reference ----
+def _lineitem():
+    """(plan, reference module, raw rows) of TPC-H lineitem at the
+    benchmark's rehearsal size (50,000 rows) from a fixed seed."""
+    import sys
+    from pathlib import Path
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.run import Bench, rehearsed
+    cfg, ref, prog = Bench(Path(root)).config("tpch_lineitem_sf10_extract")
+    cfg = rehearsed(cfg, True)
+    raw = ref.generate(cfg, 2 ** 31 + 17, True)
+    return prog, ref, raw, prog.plan(cfg, raw)
+
+
+@pytest.fixture(scope="module")
+def lineitem():
+    return _lineitem()
+
+
+def _check_against_reference(ref, raw, p, got):
+    """Served float32 rows against the float64 reference: one-hot and year
+    columns exactly; the continuous ones to 1e-5 relative, because the
+    program builds their ADV tables in float32 from float32-cast
+    dictionary values (about 1e-6 of quantity's z-score)."""
+    rows = np.flatnonzero(ref.q6_mask(raw, p))
+    assert got.shape == (rows.size, 36)
+    want = ref.features(raw, rows, ref._moments(raw))
+    np.testing.assert_array_equal(got[:, ref.EXACT], want[:, ref.EXACT])
+    np.testing.assert_allclose(got[:, ref.CONTINUOUS],
+                               want[:, ref.CONTINUOUS], rtol=1e-5)
+
+
+QGEN = [{"year": 1993, "discount": 2, "quantity": 24},
+        {"year": 1995, "discount": 6, "quantity": 25},
+        {"year": 1997, "discount": 9, "quantity": 24}]
+
+
+@pytest.mark.parametrize("p", QGEN, ids=lambda p: "{year}-{discount}-{quantity}"
+                         .format(**p))
+def test_service_where_serves_the_lineitem_reference(lineitem, p):
+    prog, ref, raw, plan = lineitem
+    from repro.serve import FeatureFrontend, default_classes
+    with FeatureService(plan, classes=default_classes()) as svc:
+        fe = FeatureFrontend(svc)
+        got = fe.result(fe.submit(where=prog.where("q6", p), klass="batch"))
+        _check_against_reference(ref, raw, p, got)
+        assert svc.stats["filtered_rows"] == got.shape[0] > 0
+        assert svc.stats["rows"] == got.shape[0]
+
+
+@pytest.mark.parametrize("lanes", ["one_exactly", "four_exactly", "across"])
+def test_service_where_lanes_cross_and_fill_blocks(lineitem, monkeypatch,
+                                                    lanes):
+    """Counts that fill one lane exactly, fill four (one whole launch)
+    exactly, and cross lane boundaries with a partial last lane: every
+    match is served once, in row order."""
+    from repro.core import pipeline
+    prog, ref, raw, plan = lineitem
+    fits = {"one_exactly": 1, "four_exactly": 4, "across": 1}[lanes]
+    p, count = next((p, c) for p in ref.PARAMS
+                    if (c := int(ref.q6_mask(raw, p).sum())) % fits == 0)
+    lane = count // fits if lanes != "across" else count // 3 - 5
+    monkeypatch.setattr(pipeline, "WHERE_LANE", lane)
+    with FeatureService(plan) as svc:
+        assert svc._executor.where_lane == lane
+        got = svc.result(svc.submit(where=prog.where("q6", p)))
+        _check_against_reference(ref, raw, p, got)
+        st = svc.stats
+        assert st["launched_rows"] == st["launches"] * 4 * lane
+        assert st["padded_rows"] == -(-count // lane) * lane - count
+        if lanes != "across":
+            assert st["padded_rows"] == 0
+
+
+def test_service_where_empty_selection_on_lineitem(lineitem):
+    prog, ref, raw, plan = lineitem
+    p = {"year": 1994, "discount": 4, "quantity": 1}     # quantity < 1
+    assert not ref.q6_mask(raw, p).any()
+    with FeatureService(plan) as svc:
+        out = svc.result(svc.submit(where=prog.where("q6", p)))
+        assert out.shape == (0, plan.out_dim)
+        assert svc.stats["launches"] == 0
+        assert svc.stats["filtered_requests"] == 1
+        assert svc.stats["filtered_rows"] == 0
+
+
+def test_where_launches_reuse_one_executable_across_match_counts(lineitem):
+    """The compaction and the gather of a where request compile once: a
+    second request with another match count runs the same executables."""
+    from repro.core import pipeline
+    prog, ref, raw, plan = lineitem
+    counts, sizes = [], []
+    with FeatureService(plan) as svc:
+        for p in QGEN:
+            counts.append(int(svc.result(svc.submit(
+                where=prog.where("q6", p))).shape[0]))
+            sizes.append((pipeline._where_compact._cache_size(),
+                          pipeline._packed_split_rows._cache_size()))
+    assert len(set(counts)) == len(counts)
+    assert sizes[1] == sizes[0] == sizes[-1]
+
+
+def test_executor_where_paths_compile_once_across_match_counts(lineitem):
+    """``filtered_rows`` and ``batch_where``, on one executor and across
+    shards, take the served path's count-independent shapes: a second
+    and third match count compile nothing new."""
+    from repro.core import pipeline
+    from repro.kernels.predicate_scan import ops as scan_ops
+    prog, ref, raw, plan = lineitem
+    ex, sx = FeatureExecutor(plan), ShardedFeatureExecutor(plan)
+    jits = (scan_ops.compact_block, pipeline._where_compact,
+            scan_ops._scan_split_ranks)
+    sizes = []
+    for p in QGEN:
+        want = np.flatnonzero(ref.q6_mask(raw, p))
+        for x in (ex, sx):
+            np.testing.assert_array_equal(x.filtered_rows(
+                prog.where("q6", p)), want)
+            rows, feats = x.batch_where(prog.where("q6", p))
+            np.testing.assert_array_equal(rows, want)
+            _check_against_reference(ref, raw, p, np.asarray(feats))
+        sizes.append(tuple(j._cache_size() for j in jits))
+    assert sizes[0] == sizes[1] == sizes[2]
+
+
+def test_explicit_row_launches_keep_their_class_shapes(lineitem):
+    """Where lanes leave explicit-row launches as they were: interactive
+    gathers one lane, batch four, over the (64, 256, 1024) buckets, and
+    neither ever coalesces with a where lane."""
+    from repro.serve import default_classes
+    prog, ref, raw, plan = lineitem
+    rng = np.random.default_rng(3)
+    sizes = (10, 100, 1000, 2500)
+    for klass, lanes in (("interactive", 1), ("batch", 4)):
+        with FeatureService(plan, classes=default_classes()) as svc:
+            ex = svc._executor
+            seen = {"rows": set(), "where": set()}
+            gather = ex._rows_future
+
+            def record(rows, seen=seen, gather=gather):
+                kind = "rows" if isinstance(rows, np.ndarray) else "where"
+                seen[kind].add(int(rows.shape[0]))
+                return gather(rows)
+
+            ex._rows_future = record
+            svc.pause()
+            reqs = [rng.integers(0, plan.n_rows, n) for n in sizes]
+            tickets = [svc.submit(r, klass=klass) for r in reqs]
+            where = svc.submit(where=prog.where("q6", QGEN[0]), klass=klass)
+            svc.resume()
+            out = svc.drain()
+        assert seen["rows"] == {lanes * b for b in (64, 256, 1024)}
+        assert seen["where"] == {lanes * ex.where_lane}
+        for r, tk in zip(reqs, tickets):
+            np.testing.assert_array_equal(out[tk], plan.host_features(r))
+        _check_against_reference(ref, raw, QGEN[0], out[where])

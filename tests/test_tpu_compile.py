@@ -89,6 +89,7 @@ def _split_programs(cs, plan, sharding):
     words = _on(sharding, ex._flat_words)
     tables = tuple(_on(sharding, t) for t in ex._device_tables())
     mask = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=sharding)
+    ranks = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=sharding)
     bounds, lut, (cols, kinds, lut_offs, lut_lens) = \
         ex._compiled_pred(cs.P_AND[0])[2]
     return {
@@ -102,9 +103,16 @@ def _split_programs(cs, plan, sharding):
             (words, _on(sharding, bounds), _on(sharding, lut)),
             dict(word_offs=offs, dbs=dbs, n=ROWS, cols=cols, kinds=kinds,
                  lut_offs=lut_offs, lut_lens=lut_lens, combine="and")),
-        "_packed_split_where": (
-            pipeline._packed_split_where, (words, tables, mask),
-            dict(dbs=dbs, word_offs=offs, cap=1024)),
+        "predicate_scan_split_ranks": (
+            scan_ops._scan_split_ranks,
+            (words, _on(sharding, bounds), _on(sharding, lut)),
+            dict(word_offs=offs, dbs=dbs, n=ROWS, cols=cols, kinds=kinds,
+                 lut_offs=lut_offs, lut_lens=lut_lens, combine="and")),
+        "_where_compact": (
+            pipeline._where_compact,
+            ((ranks,) * 4,
+             jax.ShapeDtypeStruct((4,), jnp.int32, sharding=sharding)),
+            dict(lane=pipeline.WHERE_LANE)),
         "masked_counts_split": (
             scan_ops._masked_counts_split, (words, mask),
             dict(off=offs[1], db=dbs[1], n=ROWS,
@@ -114,7 +122,8 @@ def _split_programs(cs, plan, sharding):
 
 @pytest.mark.parametrize("name", ["adv_gather_packed_rows_split",
                                   "predicate_scan_split_count",
-                                  "_packed_split_where",
+                                  "predicate_scan_split_ranks",
+                                  "_where_compact",
                                   "masked_counts_split"])
 def test_split_path_compiles_for_v5e(name, one_chip, plan):
     cs, p = plan
