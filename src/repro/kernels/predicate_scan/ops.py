@@ -21,7 +21,8 @@ Downstream pieces of the pushdown pipeline:
   the device. :func:`compact_block` is its windowed form over the mask's
   running match count (:func:`predicate_scan_split_ranks`): a fixed-size
   block of matches at a traced offset, so one compiled shape serves every
-  match count.
+  match count. A TPU renders it as one pass over the block's span of the
+  count (``compact.py``), other backends as a binary search per match.
 - :func:`masked_counts` — masked per-code histogram over a column's
   resident words (the ``kernels/hist`` machinery with a mask lane):
   count/sum/mean of the column under a predicate then follow from K
@@ -38,6 +39,8 @@ import jax.numpy as jnp
 
 from repro.kernels.bitunpack.ops import (concat_row_blocks, map_row_blocks,
                                          rows_of, unpack_rows)
+from repro.kernels.predicate_scan.compact import (CHUNK_ROWS,
+                                                  compact_block_pallas)
 from repro.kernels.predicate_scan.kernel import predicate_scan_pallas
 from repro.kernels.hist.ops import masked_hist
 
@@ -191,10 +194,13 @@ def _scan_split_ranks(flat_words, bounds, flat_lut, *, word_offs, dbs, n,
                       cols, kinds, lut_offs, lut_lens, combine):
     """Scan + running match count in ONE launch: ``ranks[i]`` is the number
     of matches in rows [0, i], so ``ranks[-1]`` is the count and
-    :func:`compact_block` finds any block of matches without a rescan."""
+    :func:`compact_block` finds any block of matches without a rescan.
+    The count runs on over padding rows that never match, to a multiple
+    of ``CHUNK_ROWS``: the compaction kernel reads it in whole tiles."""
     mask = _scan_body(flat_words, bounds, flat_lut, word_offs=word_offs,
                       dbs=dbs, n=n, cols=cols, kinds=kinds,
                       lut_offs=lut_offs, lut_lens=lut_lens, combine=combine)
+    mask = jnp.pad(mask, (0, -n % CHUNK_ROWS))
     ranks = jnp.cumsum(mask.astype(jnp.int32))
     return ranks, ranks[-1]
 
@@ -262,10 +268,22 @@ def compact_block(ranks: jnp.ndarray, off, cap: int,
     ``fill``, a valid row index, so the block feeds the indexed gather
     directly and callers slice the valid prefix off the output. ``off`` is
     traced: one compiled shape serves every offset and every count.
+
+    One result, rendered by the platform the program is compiled for: a
+    TPU walks the block's span of the count once
+    (:func:`repro.kernels.predicate_scan.compact.compact_block_pallas`);
+    elsewhere each match is a binary search of the count.
     """
-    q = off + jnp.arange(1, cap + 1, dtype=jnp.int32)
-    rows = jnp.searchsorted(ranks, q, side="left")
-    return jnp.where(rows < ranks.shape[0], rows, fill).astype(jnp.int32)
+    def searched(ranks, off):
+        q = off + jnp.arange(1, cap + 1, dtype=jnp.int32)
+        rows = jnp.searchsorted(ranks, q, side="left")
+        return jnp.where(rows < ranks.shape[0], rows, fill).astype(jnp.int32)
+
+    def walked(ranks, off):
+        return compact_block_pallas(ranks, off, cap, fill, interpret=False)
+
+    return jax.lax.platform_dependent(ranks, off, tpu=walked,
+                                      default=searched)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "fill"))

@@ -26,7 +26,9 @@ from repro.kernels.hist import masked_hist
 from repro.kernels.hist.ref import masked_hist_ref
 from repro.kernels.predicate_scan import (ScanTerm, predicate_scan,
                                           predicate_scan_split, compact_rows,
-                                          masked_counts)
+                                          compact_block, masked_counts)
+from repro.kernels.predicate_scan.compact import (CHUNK_ROWS,
+                                                  compact_block_pallas)
 from repro.kernels.predicate_scan.ref import (predicate_scan_ref,
                                               compact_rows_ref,
                                               masked_counts_ref)
@@ -177,6 +179,49 @@ def test_compact_rows_matches_reference(n, seed):
     # fill rows past the valid prefix are the fill value (gatherable)
     full = np.asarray(compact_rows(jnp.asarray(mask), cap, fill=7))
     assert (full[ref.shape[0]:] == 7).all()
+
+
+def _mask_of(n, rows=(), p=0.0, every=0, seed=0):
+    mask = np.random.default_rng(seed).random(n) < p
+    mask[list(rows)] = True
+    if every:
+        mask[::every] = True
+    return mask
+
+
+# (mask, off, cap, fill): the span walk's edges. CHUNK_ROWS (8,192) rows
+# come in one copy and are placed 1,024 at a time
+COMPACT_CASES = {
+    "empty": (_mask_of(5000), 0, 256, 0),
+    "first_and_last_row": (_mask_of(20000, rows=(0, 19999)), 0, 128, 0),
+    "q6_density": (_mask_of(40000, p=0.018, seed=1), 200, 1024, 0),
+    "half": (_mask_of(20000, p=0.5, seed=2), 3000, 4096, 0),
+    "every_row": (_mask_of(10000, p=1.0), 1000, 2048, 0),
+    "window_mid_tile_across_chunks": (_mask_of(30000, p=0.3, seed=3), 2000,
+                                      2500, 0),
+    "off_past_count": (_mask_of(5000, p=0.5, seed=4), 4000, 256, 7),
+    "ragged_n": (_mask_of(2 * CHUNK_ROWS + 77, p=0.1, seed=5), 0, 2048, 0),
+    "fill_nonzero": (_mask_of(9000, p=0.2, seed=6), 100, 2048, 8999),
+    "sparse_span": (_mask_of(6 * 4 * CHUNK_ROWS, every=4 * CHUNK_ROWS + 13),
+                    1, 96, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(COMPACT_CASES))
+def test_compact_block_kernel_matches_reference(case):
+    """The span-walk kernel (what a TPU runs, here interpreted) gives
+    matches [off, off + cap) in row order and ``fill`` after them, bit for
+    bit what the binary-search rendering gives."""
+    mask, off, cap, fill = COMPACT_CASES[case]
+    ref = compact_rows_ref(mask)[off:off + cap]
+    want = np.full(cap, fill, np.int32)
+    want[:ref.shape[0]] = ref
+    ranks = jnp.cumsum(jnp.asarray(mask, jnp.int32))
+    got = np.asarray(compact_block_pallas(ranks, off, cap, fill,
+                                          interpret=True))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(compact_block(ranks, off, cap, fill)), want)
 
 
 @settings(max_examples=20, deadline=None)

@@ -4,7 +4,8 @@ Nothing runs: each test lowers and compiles one program for a v5e that is
 described, not attached (the TPU compiler is part of the installed jaxlib),
 at the widths of the serving schema ``chip_smoke.py`` drives (``age``,
 ``state``, ``income``, ``device``; device widths 8+8+8+2 bits, 58 feature
-columns) over a 64K-row table. The Pallas kernels compile with
+columns) over a 64K-row table; the where compaction at the size of the
+TPC-H SF10 lineitem table it serves. The Pallas kernels compile with
 ``interpret=False``, which is what a TPU backend resolves ``None`` to; the
 kernels the compiler refuses are pinned by a test that
 ``FeatureExecutor(use_kernel=True)`` raises with the compiler's reason.
@@ -30,8 +31,11 @@ from repro.kernels import KernelRefused
 from repro.kernels.bitunpack.kernel import bitunpack_pallas
 from repro.kernels.hist.kernel import masked_hist_pallas
 from repro.kernels.predicate_scan import ops as scan_ops
+from repro.kernels.predicate_scan.compact import CHUNK_ROWS
 
 ROWS = 1 << 16
+# rows of the TPC-H SF10 lineitem table the filtered pull compacts over
+LINEITEM_ROWS = 59_986_052
 REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
@@ -89,7 +93,10 @@ def _split_programs(cs, plan, sharding):
     words = _on(sharding, ex._flat_words)
     tables = tuple(_on(sharding, t) for t in ex._device_tables())
     mask = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=sharding)
-    ranks = jax.ShapeDtypeStruct((ROWS,), jnp.int32, sharding=sharding)
+    # the running count as the scan leaves it for the full lineitem table
+    ranks = jax.ShapeDtypeStruct(
+        (-(-LINEITEM_ROWS // CHUNK_ROWS) * CHUNK_ROWS,), jnp.int32,
+        sharding=sharding)
     bounds, lut, (cols, kinds, lut_offs, lut_lens) = \
         ex._compiled_pred(cs.P_AND[0])[2]
     return {
@@ -128,7 +135,10 @@ def _split_programs(cs, plan, sharding):
 def test_split_path_compiles_for_v5e(name, one_chip, plan):
     cs, p = plan
     fn, args, statics = _split_programs(cs, p, one_chip)[name]
-    _compile(fn, *args, **statics)
+    compiled = _compile(fn, *args, **statics)
+    if name == "_where_compact":
+        # a TPU walks each lane's span in the compaction kernel
+        assert compiled.as_text().count("tpu_custom_call") >= 4
 
 
 def test_masked_hist_kernel_compiles_for_v5e(one_chip, plan):
